@@ -21,6 +21,32 @@ from repro.optim import base as optbase
 from repro.train import loop
 
 
+def kfac_setup(optimizer: str, preset: str, stagger: bool = False,
+               stagger_splits: int = 4):
+    """(init, loss_fn, accuracy, Kfac) for a preset: the paper's §6
+    optimizer settings (T_brand=5, T_inv=25 cadence, paper lr/damping
+    schedules) on the preset's VGG."""
+    if preset == "paper":
+        cfg = VggConfig(stages=(64, 128, 256, 512, 512), fc_hidden=2048,
+                        n_stat=256)
+        r = 230
+    else:
+        cfg = VggConfig(stages=(16, 32, 64), fc_hidden=512, n_stat=64)
+        r = 96
+
+    init, loss_fn, accuracy, taps = make_vgg(cfg)
+    kcfg = kfac_lib.KfacConfig(
+        policy=policy_lib.PolicyConfig(variant=optimizer, r=r,
+                                       max_dense_dim=4096),
+        lr=optbase.paper_lr_schedule(steps_per_epoch=50),
+        damping_phi=optbase.paper_damping_schedule(steps_per_epoch=50),
+        weight_decay=7e-4, clip=0.5,
+        T_updt=5, T_inv=25, T_brand=5, T_rsvd=25, T_corct=25,
+        stagger=stagger, stagger_splits=stagger_splits,
+        fallback_lr=optbase.constant(3e-3))
+    return init, loss_fn, accuracy, kfac_lib.Kfac(kcfg, taps)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--optimizer", default="bkfac",
@@ -35,25 +61,9 @@ def main():
     ap.add_argument("--stagger-splits", type=int, default=4)
     args = ap.parse_args()
 
-    if args.preset == "paper":
-        cfg = VggConfig(stages=(64, 128, 256, 512, 512), fc_hidden=2048,
-                        n_stat=256)
-        r = 230
-    else:
-        cfg = VggConfig(stages=(16, 32, 64), fc_hidden=512, n_stat=64)
-        r = 96
-
-    init, loss_fn, accuracy, taps = make_vgg(cfg)
-    kcfg = kfac_lib.KfacConfig(
-        policy=policy_lib.PolicyConfig(variant=args.optimizer, r=r,
-                                       max_dense_dim=4096),
-        lr=optbase.paper_lr_schedule(steps_per_epoch=50),
-        damping_phi=optbase.paper_damping_schedule(steps_per_epoch=50),
-        weight_decay=7e-4, clip=0.5,
-        T_updt=5, T_inv=25, T_brand=5, T_rsvd=25, T_corct=25,
-        stagger=args.stagger, stagger_splits=args.stagger_splits,
-        fallback_lr=optbase.constant(3e-3))
-    opt = kfac_lib.Kfac(kcfg, taps)
+    init, loss_fn, accuracy, opt = kfac_setup(
+        args.optimizer, args.preset, stagger=args.stagger,
+        stagger_splits=args.stagger_splits)
     # run_kfac_training drives the work scheduler (staggered iff
     # cfg.stagger); pass dist=DistSpec(mesh=..., curvature_axis=...)
     # there to also shard the factor work across a device mesh

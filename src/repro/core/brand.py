@@ -20,13 +20,10 @@ Stacked-native: the symmetric path (``sym_brand_update`` / ``ea_brand_step``
 bucket of K-factors (scanned layers, MoE experts, cross-layer shape
 classes) updates in one batched call.
 
-``use_kernel`` routes the two O(d)-sized ops of the symmetric update — the
-projection panel (C, A⊥) and the tall-skinny QR of A⊥ — through the Pallas
-kernels (``kernels/ops.py::brand_panel`` + ``cholqr2``); the remaining
-O((r+n)²) eigenproblem stays in XLA.  The default path keeps Householder
-``jnp.linalg.qr`` (the original oracle semantics); both agree up to
-rotations inside degenerate eigenspaces, which the represented matrix
-U diag(D) Uᵀ is invariant to.
+The two O(d)-sized ops of the symmetric update — the projection panel
+(C, A⊥) and the tall-skinny QR of A⊥ — go through ``kernels/ops.py``
+(``brand_panel`` + ``cholqr2``: Pallas on TPU, the ``ref.py`` oracles
+elsewhere); the remaining O((r+n)²) eigenproblem stays in XLA.
 """
 from __future__ import annotations
 
@@ -35,6 +32,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.kernels.ref import mt as _mt
 
 Array = jax.Array
@@ -85,8 +83,7 @@ def brand_update(U: Array, D: Array, V: Array, A: Array, B: Array
     return U_new, Dm, V_new
 
 
-def sym_brand_update(U: Array, D: Array, A: Array, use_kernel: bool = False
-                     ) -> Tuple[Array, Array]:
+def sym_brand_update(U: Array, D: Array, A: Array) -> Tuple[Array, Array]:
     """Symmetric Brand update (paper Alg 3):  X̂ = U diag(D) Uᵀ + A Aᵀ.
 
     U: (*stack, d, r) column-orthonormal, D: (*stack, r) descending,
@@ -97,18 +94,12 @@ def sym_brand_update(U: Array, D: Array, A: Array, use_kernel: bool = False
         X̂ = [U Q] [[diag(D)+CCᵀ, CRᵀ],[RCᵀ, RRᵀ]] [U Q]ᵀ
     and the middle (r+n)² matrix is symmetric — one small eigh finishes it.
 
-    With ``use_kernel`` the O(d·r·n) panel and the O(d·n²) tall-skinny QR
-    run as batched Pallas launches (``brand_panel`` + CholeskyQR2); the
-    whole light update is then linear in d with no XLA QR left.
+    The O(d·r·n) panel and the O(d·n²) tall-skinny QR run as batched
+    launches (``brand_panel`` + CholeskyQR2), so the whole light update is
+    linear in d with no XLA QR left.
     """
-    if use_kernel:
-        from repro.kernels import ops as kops
-        C, A_perp = kops.brand_panel(U, A)           # (…, r, n), (…, d, n)
-        Q, R = kops.cholqr2(A_perp)                  # (…, d, n), (…, n, n)
-    else:
-        C = _mt(U) @ A
-        A_perp = A - U @ C
-        Q, R = jnp.linalg.qr(A_perp)
+    C, A_perp = kops.brand_panel(U, A)               # (…, r, n), (…, d, n)
+    Q, R = kops.cholqr2(A_perp)                      # (…, d, n), (…, n, n)
     top = jnp.concatenate([_batched_diag(D) + C @ _mt(C), C @ _mt(R)],
                           axis=-1)
     bot = jnp.concatenate([R @ _mt(C), R @ _mt(R)], axis=-1)
@@ -118,8 +109,8 @@ def sym_brand_update(U: Array, D: Array, A: Array, use_kernel: bool = False
     return U_new, Dm
 
 
-def ea_brand_step(U: Array, D: Array, X: Array, rho: float, r: int,
-                  use_kernel: bool = False) -> Tuple[Array, Array]:
+def ea_brand_step(U: Array, D: Array, X: Array, rho: float, r: int
+                  ) -> Tuple[Array, Array]:
     """One B-KFAC K-factor inverse-representation step (paper Alg 4).
 
     Held state (U, D) has rank r+n (from the previous step).  We truncate to
@@ -133,8 +124,7 @@ def ea_brand_step(U: Array, D: Array, X: Array, rho: float, r: int,
     Returns (U', D') of rank r+n.
     """
     Ut, Dt = truncate(U, D, r)
-    return sym_brand_update(Ut, rho * Dt, jnp.sqrt(1.0 - rho) * X,
-                            use_kernel=use_kernel)
+    return sym_brand_update(Ut, rho * Dt, jnp.sqrt(1.0 - rho) * X)
 
 
 def init_from_factor(X: Array, m: int) -> Tuple[Array, Array]:

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import buckets, kfactor, policy, precond, schedule
+from repro.kernels import ops
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.optim import adamw as _adamw
@@ -74,7 +75,6 @@ class KfacConfig:
     weight_decay: float = 7e-4
     clip: float = 0.07              # global-norm clip on the update
     spectrum_continuation: bool = True
-    use_kernels: bool = False       # route hot matmuls via kernels/ops.py
     bucketed: bool = True           # cross-layer shape-class super-batching
     T_updt: int = 25
     T_inv: int = 250                # kfac / rkfac heavy period
@@ -179,6 +179,10 @@ class Kfac:
     factor bucket's batch axis across a mesh axis; when attached, the
     bucketed factor work is delegated to it.  Duck-typed so core never
     imports the distributed package.
+
+    ``mesh`` is the mesh the step runs over, engine or not (None: one
+    device); ``repro.specs.DistSpec.attach`` sets it.  The kernels
+    launched outside the engine run replicated over it.
     """
 
     def __init__(self, cfg: KfacConfig, taps: Dict[str, TapInfo],
@@ -186,6 +190,7 @@ class Kfac:
         self.cfg = cfg
         self.taps = dict(taps)
         self.curvature = curvature
+        self.mesh = getattr(curvature, "mesh", None)
         self.specs = {}
         for name, t in self.taps.items():
             self.specs[name] = dict(
@@ -321,7 +326,7 @@ class Kfac:
         keys = jax.random.split(key, count)
         flat = kfactor.bucket_factor_step(
             spec, flat, Xf, keys, first, stats, light,
-            ((0, count),) if heavy_b else (), self.cfg.use_kernels)
+            ((0, count),) if heavy_b else ())
         return jax.tree_util.tree_map(
             lambda x: x.reshape(stack + x.shape[1:]), flat)
 
@@ -330,11 +335,10 @@ class Kfac:
         """Preconditioned step for W (same shape as grad_w).
 
         Stacked-native end to end: damping, continuation, and the two-sided
-        application are batched over the tap's stack, so ``use_kernels``
-        covers scanned layers / expert stacks with single batched (fused)
-        Pallas launches instead of vmapped 2D fallbacks.
+        application are batched over the tap's stack, so scanned layers /
+        expert stacks run as single batched (fused) kernel launches instead
+        of vmapped 2D fallbacks.
         """
-        use_k = self.cfg.use_kernels
         cont = self.cfg.spectrum_continuation
         # NS-mode sides hold a dense damped inverse in U — plain GEMM apply
         dense_g = self.specs[name]["G"].mode is kfactor.Mode.NS
@@ -343,13 +347,13 @@ class Kfac:
             # Alg 8: step from gradient factors; grad_w is unused (stop-grad)
             S = precond.precondition_linear_with_damping(
                 g_factor, a_factor, st.G.U, st.G.D, st.A.U, st.A.D, phi,
-                continuation=cont, use_kernel=use_k,
+                continuation=cont,
                 dense_g=dense_g, dense_a=dense_a)
         else:
             J = jnp.swapaxes(grad_w, -1, -2).astype(jnp.float32)
             S = precond.precondition_with_damping(
                 J, st.G.U, st.G.D, st.A.U, st.A.D, phi,
-                continuation=cont, use_kernel=use_k,
+                continuation=cont,
                 dense_g=dense_g, dense_a=dense_a)
         return jnp.swapaxes(S, -1, -2)       # back to (d_in, d_out) layout
 
@@ -410,7 +414,7 @@ class Kfac:
                 return kfactor.bucket_factor_step_async(
                     bucket.spec, st, X, keys, first, work.stats,
                     work.light, work.heavy[bi], launch, land, buf,
-                    self.cfg.use_kernels, landed=landed)
+                    landed=landed)
         states, X_all = self.collect_factor_operands(factors, acts,
                                                      probe_grads, n_tokens)
         inflight = dict(inflight)
@@ -507,7 +511,6 @@ class Kfac:
         a bucket's J gather is tens of MB per step on real models.
         """
         cont = self.cfg.spectrum_continuation
-        use_k = self.cfg.use_kernels
         out = {}
         for pbi, bucket in enumerate(self.precond_buckets):
             ent = bucket.entries
@@ -538,7 +541,7 @@ class Kfac:
                         -1, -2).astype(jnp.float32)      # (B, d_in, n)
                     S = precond.precondition_linear_with_damping(
                         afac, gfac, U_a, D_a, U_g, D_g, phi,
-                        continuation=cont, use_kernel=use_k,
+                        continuation=cont,
                         dense_g=dense_swap_g, dense_a=dense_swap_a)
                 else:
                     J = buckets.gather(ent, {
@@ -547,7 +550,7 @@ class Kfac:
                         for e in ent}).astype(jnp.float32)
                     S = precond.precondition_with_damping(
                         J, U_a, D_a, U_g, D_g, phi,
-                        continuation=cont, use_kernel=use_k,
+                        continuation=cont,
                         dense_g=dense_swap_g, dense_a=dense_swap_a)
             out.update({name: Se for (name, _), Se
                         in buckets.scatter(ent, S).items()})
@@ -573,7 +576,20 @@ class Kfac:
         escalation knob (train/health.py).  A scale of exactly 1.0 is
         bit-inert (float multiply by 1.0 is exact), which is what keeps
         the health-guarded step's healthy-run outputs identical to the
-        unguarded step's."""
+        unguarded step's.
+
+        On a mesh (``mesh``, set by ``repro.specs.DistSpec.attach``) the
+        Pallas kernels launched outside the curvature engine's
+        ``shard_map`` bodies run replicated over it
+        (``kernels.ops.kernel_mesh``)."""
+        with ops.kernel_mesh(self.mesh):
+            return self._update(grads, state, params, acts, probe_grads,
+                                n_tokens, rng, work, do_stats, do_light,
+                                do_heavy, landing, damping_scale)
+
+    def _update(self, grads, state, params, acts, probe_grads, n_tokens,
+                rng, work, do_stats, do_light, do_heavy, landing,
+                damping_scale):
         cfg = self.cfg
         if work is None:
             from repro import specs as specs_lib

@@ -168,14 +168,13 @@ def ea_update_m_rows(M_rows: Array, X: Array, r0, rb: int, rho: float,
     return keep * M_rows + coef * upd
 
 
-def brand_step(spec: KFactorSpec, st: KFactorState, X: Array, first: Array,
-               use_kernel: bool = False) -> KFactorState:
+def brand_step(spec: KFactorSpec, st: KFactorState, X: Array, first: Array
+               ) -> KFactorState:
     """B-update (Alg 4): truncate to r then symmetric Brand with the EA term.
 
     Stacked-native: st/X may carry leading stack axes (``first`` is the
     global scalar flag) — a whole bucket of Brand factors steps as one
-    batched panel + CholeskyQR2 + eigh.  ``use_kernel`` routes the O(d)
-    panel and QR through Pallas (see ``brand.sym_brand_update``).
+    batched panel + CholeskyQR2 + eigh (see ``brand.sym_brand_update``).
 
     On the first-ever stats batch the state is empty — initialize from the
     factor directly (exact, low-memory)."""
@@ -184,8 +183,7 @@ def brand_step(spec: KFactorSpec, st: KFactorState, X: Array, first: Array,
         return KFactorState(U=U0, D=D0, M=st.M, aux=st.aux)
 
     def _update(_):
-        U, D = brand.ea_brand_step(st.U, st.D, X, spec.rho, spec.r,
-                                   use_kernel=use_kernel)
+        U, D = brand.ea_brand_step(st.U, st.D, X, spec.rho, spec.r)
         if U.shape[-1] > spec.width:  # r + n_stat exceeded d: re-truncate
             U, D = U[..., :, :spec.width], D[..., :spec.width]
         return KFactorState(U=U, D=D, M=st.M, aux=st.aux)
@@ -395,8 +393,7 @@ def stats_step(spec: KFactorSpec, st: KFactorState, X: Array, first: Array
 
 
 def inverse_rep_step(spec: KFactorSpec, st: KFactorState, X: Array,
-                     key: Array, first: Array, heavy: Array,
-                     use_kernel: bool = False) -> KFactorState:
+                     key: Array, first: Array, heavy: Array) -> KFactorState:
     """Scheduled inverse-representation update (one 2-D factor).
 
     ``heavy`` selects the periodic heavy op for the mode (RSVD overwrite /
@@ -413,13 +410,13 @@ def inverse_rep_step(spec: KFactorSpec, st: KFactorState, X: Array,
         return jax.lax.cond(heavy, lambda s: ns_overwrite(spec, s),
                             lambda s: s, st)
     if spec.mode is Mode.BRAND:
-        return brand_step(spec, st, X, first, use_kernel)
+        return brand_step(spec, st, X, first)
     if spec.mode is Mode.BRAND_RSVD:
-        st = brand_step(spec, st, X, first, use_kernel)
+        st = brand_step(spec, st, X, first)
         return jax.lax.cond(heavy, lambda s: rsvd_overwrite(spec, s, key),
                             lambda s: s, st)
     if spec.mode is Mode.BRAND_CORR:
-        st = brand_step(spec, st, X, first, use_kernel)
+        st = brand_step(spec, st, X, first)
         return jax.lax.cond(heavy, lambda s: light_correction(spec, s, key),
                             lambda s: s, st)
     raise ValueError(spec.mode)
@@ -530,22 +527,20 @@ def heavy_from_snapshot(spec: KFactorSpec, buf: InflightState,
     return out.U, out.D, out.aux
 
 
-def replay_panels(spec: KFactorSpec, U: Array, D: Array, panels: Array,
-                  use_kernel: bool = False) -> Tuple[Array, Array]:
+def replay_panels(spec: KFactorSpec, U: Array, D: Array, panels: Array
+                  ) -> Tuple[Array, Array]:
     """Replay the interim light panels (oldest first) onto an incoming
     inverse rep — the landed state then carries every Brand absorb the
     live state received while the heavy op was in flight."""
     for j in range(panels.shape[1]):
-        U, D = brand.ea_brand_step(U, D, panels[:, j], spec.rho, spec.r,
-                                   use_kernel=use_kernel)
+        U, D = brand.ea_brand_step(U, D, panels[:, j], spec.rho, spec.r)
         if U.shape[-1] > spec.width:
             U, D = U[..., :, :spec.width], D[..., :spec.width]
     return U, D
 
 
 def land_swap(spec: KFactorSpec, st: KFactorState, buf: InflightState,
-              lo: int, hi: int, use_kernel: bool = False,
-              landed=None) -> Tuple[KFactorState, InflightState]:
+              lo: int, hi: int, landed=None) -> Tuple[KFactorState, InflightState]:
     """Swap the landed inverse rep of slots [lo, hi) into the live state
     atomically.  ``landed`` is an optionally pre-computed (U, D, aux)
     triple from an overlapped dispatch; when absent the heavy op runs
@@ -560,7 +555,7 @@ def land_swap(spec: KFactorSpec, st: KFactorState, buf: InflightState,
     else:
         U, D, aux = landed
     if spec.mode in _HAS_BRAND:
-        U, D = replay_panels(spec, U, D, buf.panels[lo:hi], use_kernel)
+        U, D = replay_panels(spec, U, D, buf.panels[lo:hi])
     ok = buf.live[lo:hi]
     U = jnp.where(ok[:, None, None], U, st.U[lo:hi])
     D = jnp.where(ok[:, None], D, st.D[lo:hi])
@@ -574,8 +569,7 @@ def land_swap(spec: KFactorSpec, st: KFactorState, buf: InflightState,
 
 def bucket_factor_step(spec: KFactorSpec, st: KFactorState, X: Array,
                        keys: Array, first: Array, stats: bool, light: bool,
-                       heavy_ranges, use_kernel: bool = False
-                       ) -> KFactorState:
+                       heavy_ranges) -> KFactorState:
     """One scheduled step for a whole shape-class bucket: st/X carry one
     flat batch axis (B, …); ``keys`` is (B, 2).  This is THE per-bucket
     program — the replicated bucketed optimizer, the per-tap comparison
@@ -595,7 +589,7 @@ def bucket_factor_step(spec: KFactorSpec, st: KFactorState, X: Array,
     heavy_ranges = tuple(heavy_ranges)
     if (light or heavy_ranges) and spec.mode in _HAS_BRAND:
         with obs_trace.span("light_brand"):
-            st = brand_step(spec, st, X, first, use_kernel)
+            st = brand_step(spec, st, X, first)
     for lo, hi in heavy_ranges:
         with obs_trace.span(f"heavy_{lo}_{hi}"):
             sub = jax.tree_util.tree_map(lambda x: x[lo:hi], st)
@@ -609,7 +603,7 @@ def bucket_factor_step_async(spec: KFactorSpec, st: KFactorState, X: Array,
                              keys: Array, first: Array, stats: bool,
                              light: bool, heavy_ranges, launch_ranges,
                              land_ranges, buf: Optional[InflightState],
-                             use_kernel: bool = False, landed=None
+                             landed=None
                              ) -> Tuple[KFactorState,
                                         Optional[InflightState]]:
     """One scheduled step of the async double-buffered pipeline for a
@@ -630,7 +624,7 @@ def bucket_factor_step_async(spec: KFactorSpec, st: KFactorState, X: Array,
     land range, from an overlapped dispatch (AsyncInverseRunner).
     """
     st = bucket_factor_step(spec, st, X, keys, first, stats, light,
-                            heavy_ranges, use_kernel)
+                            heavy_ranges)
     if buf is None:
         return st, None
     if light:
@@ -640,7 +634,7 @@ def bucket_factor_step_async(spec: KFactorSpec, st: KFactorState, X: Array,
             buf = launch_snapshot(buf, st, keys, lo, hi)
     for i, (lo, hi) in enumerate(tuple(land_ranges)):
         with obs_trace.span(f"land_{lo}_{hi}"):
-            st, buf = land_swap(spec, st, buf, lo, hi, use_kernel,
+            st, buf = land_swap(spec, st, buf, lo, hi,
                                 landed=None if landed is None
                                 else landed[i])
     return st, buf

@@ -24,7 +24,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ref import mt as _mt, scal as _scal
+from repro.kernels import ops as kops
+from repro.kernels.ref import mt as _mt
 
 Array = jax.Array
 
@@ -76,42 +77,34 @@ def _lam_safe(lam: Array) -> Array:
     return jnp.maximum(jnp.asarray(lam), _LAM_EPS)
 
 
-def apply_inv_right(J: Array, U: Array, D: Array, lam: Array,
-                    use_kernel: bool = False) -> Array:
+def apply_inv_right(J: Array, U: Array, D: Array, lam: Array) -> Array:
     """J @ (U diag(D) Uᵀ + λI)⁻¹  — right application (A-side).
 
     J: (..., p, d), U: (..., d, w).  O(p·d·w): two tall-skinny matmuls +
-    rank-1 work.
+    rank-1 work, fused in ``kops.lowrank_apply``.
     """
     lam = _lam_safe(lam)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.lowrank_apply(J, U, lowrank_inv_diag(D, lam), lam)
-    T = J @ U                                    # (..., p, w)
-    T = T * lowrank_inv_diag(D, lam)[..., None, :]
-    return T @ _mt(U) + J / _scal(lam, J)
+    return kops.lowrank_apply(J, U, lowrank_inv_diag(D, lam), lam)
 
 
-def apply_inv_left(J: Array, U: Array, D: Array, lam: Array,
-                   use_kernel: bool = False) -> Array:
+def apply_inv_left(J: Array, U: Array, D: Array, lam: Array) -> Array:
     """(U diag(D) Uᵀ + λI)⁻¹ @ J — left application (Γ-side).
     J: (..., d, p)."""
-    return _mt(apply_inv_right(_mt(J), U, D, lam, use_kernel))
+    return _mt(apply_inv_right(_mt(J), U, D, lam))
 
 
 def kfac_precondition(J: Array,
                       U_g: Array, D_g: Array, lam_g: Array,
                       U_a: Array, D_a: Array, lam_a: Array,
-                      use_kernel: bool = False,
                       dense_g: bool = False, dense_a: bool = False) -> Array:
     """Full quadratic application (Alg 1): S = Γ̄⁻¹ J Ā⁻¹.
 
     J is the layer gradient in matrix form (d_out, d_in) = Mat(g);
     Γ̄ is (d_out, d_out), Ā is (d_in, d_in).
 
-    With ``use_kernel`` the whole two-sided application dispatches to the
-    fused Pallas path (one launch sequence, J resident, no transposes, no
-    HBM intermediate) instead of two ``lowrank_apply`` round-trips.
+    The two-sided application dispatches to the fused path (one launch
+    sequence, J resident, no transposes, no HBM intermediate) instead of
+    two ``lowrank_apply`` round-trips.
 
     ``dense_g``/``dense_a`` mark NS-mode factors: U on that side *is* the
     dense damped inverse (U ≈ (M + λ̂I)⁻¹, symmetric), so the application
@@ -119,24 +112,17 @@ def kfac_precondition(J: Array,
     λ̂ was baked in at the NS refresh.
     """
     if dense_g or dense_a:
-        M = J @ U_a if dense_a else apply_inv_right(J, U_a, D_a, lam_a,
-                                                    use_kernel)
-        return U_g @ M if dense_g else apply_inv_left(M, U_g, D_g, lam_g,
-                                                      use_kernel)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        lam_g, lam_a = _lam_safe(lam_g), _lam_safe(lam_a)
-        return kops.precond_fused(J,
-                                  U_g, lowrank_inv_diag(D_g, lam_g), lam_g,
-                                  U_a, lowrank_inv_diag(D_a, lam_a), lam_a)
-    M = apply_inv_right(J, U_a, D_a, lam_a)      # J Ā⁻¹
-    return apply_inv_left(M, U_g, D_g, lam_g)    # Γ̄⁻¹ (·)
+        M = J @ U_a if dense_a else apply_inv_right(J, U_a, D_a, lam_a)
+        return U_g @ M if dense_g else apply_inv_left(M, U_g, D_g, lam_g)
+    lam_g, lam_a = _lam_safe(lam_g), _lam_safe(lam_a)
+    return kops.precond_fused(J,
+                              U_g, lowrank_inv_diag(D_g, lam_g), lam_g,
+                              U_a, lowrank_inv_diag(D_a, lam_a), lam_a)
 
 
 def kfac_precondition_linear(G: Array, A: Array,
                              U_g: Array, D_g: Array, lam_g: Array,
                              U_a: Array, D_a: Array, lam_a: Array,
-                             use_kernel: bool = False,
                              dense_g: bool = False, dense_a: bool = False
                              ) -> Array:
     """Alg 8 — linear-in-d application from gradient factors.
@@ -151,9 +137,9 @@ def kfac_precondition_linear(G: Array, A: Array,
     ``dense_a`` as in ``kfac_precondition`` (NS sides apply by GEMM).
     """
     Gp = (U_g @ G if dense_g
-          else apply_inv_left(G, U_g, D_g, lam_g, use_kernel))
+          else apply_inv_left(G, U_g, D_g, lam_g))
     Ap = (_mt(A) @ U_a if dense_a
-          else apply_inv_right(_mt(A), U_a, D_a, lam_a, use_kernel))
+          else apply_inv_right(_mt(A), U_a, D_a, lam_a))
     return Gp @ Ap
 
 
@@ -171,7 +157,6 @@ def precondition_with_damping(J: Array,
                               U_a: Array, D_a: Array,
                               phi: Array, *,
                               continuation: bool = True,
-                              use_kernel: bool = False,
                               dense_g: bool = False,
                               dense_a: bool = False) -> Array:
     """Damping + spectrum continuation + full quadratic application for a
@@ -191,7 +176,7 @@ def precondition_with_damping(J: Array,
         D_a, lam_a = _damped(D_a, phi, continuation)
     if not dense_g:
         D_g, lam_g = _damped(D_g, phi, continuation)
-    return kfac_precondition(J, U_g, D_g, lam_g, U_a, D_a, lam_a, use_kernel,
+    return kfac_precondition(J, U_g, D_g, lam_g, U_a, D_a, lam_a,
                              dense_g=dense_g, dense_a=dense_a)
 
 
@@ -200,7 +185,6 @@ def precondition_linear_with_damping(G: Array, A: Array,
                                      U_a: Array, D_a: Array,
                                      phi: Array, *,
                                      continuation: bool = True,
-                                     use_kernel: bool = False,
                                      dense_g: bool = False,
                                      dense_a: bool = False) -> Array:
     """Damping + continuation + Alg-8 linear application (from gradient
@@ -212,7 +196,7 @@ def precondition_linear_with_damping(G: Array, A: Array,
     if not dense_g:
         D_g, lam_g = _damped(D_g, phi, continuation)
     return kfac_precondition_linear(G, A, U_g, D_g, lam_g,
-                                    U_a, D_a, lam_a, use_kernel,
+                                    U_a, D_a, lam_a,
                                     dense_g=dense_g, dense_a=dense_a)
 
 

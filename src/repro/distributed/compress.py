@@ -63,7 +63,7 @@ def compress(g: Array, err: Array, q_prev: Optional[Array], cfg
     P, _ = jnp.linalg.qr(P)                           # orthonormal basis
     Q = G2.T @ P                                      # (n, q)
     approx = (P @ Q.T).reshape(shape)
-    new_err = g.astype(jnp.float32) - approx
+    new_err = G2.reshape(shape) - approx   # of g + err: nothing is dropped
     return P, Q, new_err
 
 

@@ -13,7 +13,7 @@ is that idea applied to the *bucketed* pipeline of ``core/buckets.py``:
     **curvature axis** with a round-robin slot → device assignment
     (``buckets.shard_perm``): slot ``s`` lives on device ``s % N``, so
     every device owns an equal ``⌈B/N⌉`` share of every bucket;
-  * inside ``jax.experimental.shard_map`` each device runs the SAME
+  * inside ``jax.shard_map`` each device runs the SAME
     per-bucket program as the replicated path
     (``kfactor.bucket_factor_step``) on its local shard — stats, Brand,
     and the scheduled heavy ranges all cost 1/N of the replicated work;
@@ -78,7 +78,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import buckets, kfactor, schedule
@@ -184,6 +183,7 @@ class CurvatureEngine:
         eng = cls(mesh, axis, opt.factor_buckets, row_axis=row_axis,
                   compress_rank=compress_rank)
         opt.curvature = eng
+        opt.mesh = mesh
         return eng
 
     # -- job accounting (benchmarks / logs) --------------------------------
@@ -257,8 +257,7 @@ class CurvatureEngine:
             return self._bucket_step(bucket.spec, self.plans[bi],
                                      self.row_blocks[bi], st, X,
                                      keys, first, work.stats, work.light,
-                                     work.heavy[bi], launch, land, buf,
-                                     opt.cfg.use_kernels)
+                                     work.heavy[bi], launch, land, buf)
 
         return opt._bucketed_factor_work(factors, inflight, acts,
                                          probe_grads, n_tokens, rng,
@@ -324,7 +323,7 @@ class CurvatureEngine:
     def _bucket_step(self, spec, plan: ShardPlan, rb: Optional[int],
                      st: KFactorState, X: Array, keys: Array,
                      first: Array, stats: bool, light: bool, ranges,
-                     launch, land, buf, use_kernel: bool):
+                     launch, land, buf):
         """One bucket's step under shard_map: each curvature member runs
         the shared per-bucket program on its ⌈B/N⌉ local slots, then
         all-gathers the O(d·r) low-rank rep; the O(d²) dense M — live
@@ -347,8 +346,7 @@ class CurvatureEngine:
             same math when M is row-sharded."""
             if rb is None:
                 return kfactor.bucket_factor_step(
-                    spec, st, X, keys, first, stats, light, local_heavy,
-                    use_kernel)
+                    spec, st, X, keys, first, stats, light, local_heavy)
             if stats:
                 with obs_trace.span("stats_rows"):
                     r0 = jax.lax.axis_index(row_axis) * rb
@@ -357,8 +355,7 @@ class CurvatureEngine:
                     st = KFactorState(U=st.U, D=st.D, M=M, aux=st.aux)
             if (light or local_heavy) and spec.mode in kfactor._HAS_BRAND:
                 with obs_trace.span("light_brand"):
-                    st = kfactor.brand_step(spec, st, X, first,
-                                            use_kernel)
+                    st = kfactor.brand_step(spec, st, X, first)
             for llo, lhi in local_heavy:
                 with obs_trace.span(f"heavy_{llo}_{lhi}"):
                     st = self._heavy_rows(spec, st, keys, llo, lhi, rb)
@@ -369,11 +366,11 @@ class CurvatureEngine:
                 st = sync_local(st, X, keys, first)
                 return self._gather_rep(st)
 
-            out = shard_map(
+            out = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(st_in, P(axis), P(axis), P()),
                 out_specs=st_out,
-                check_rep=False,
+                check_vma=False,
             )(st, X, keys, first)
             # U/D came back gathered in device-major layout; M sharded in
             # the same layout.  One static take restores slot order
@@ -389,7 +386,7 @@ class CurvatureEngine:
             if rb is None:
                 st, buf = kfactor.bucket_factor_step_async(
                     spec, st, X, keys, first, stats, light, local_heavy,
-                    local_launch, local_land, buf, use_kernel)
+                    local_launch, local_land, buf)
                 return self._gather_rep(st), buf
             # 2D path: row-block stats first (exact), then — only when
             # this step's local shard fires or lands heavy work — gather
@@ -411,8 +408,7 @@ class CurvatureEngine:
                 buff = dataclasses.replace(buf, M=g1(buf.M))
                 stf, buff = kfactor.bucket_factor_step_async(
                     spec, stf, X, keys, first, False, light,
-                    local_heavy, local_launch, local_land, buff,
-                    use_kernel)
+                    local_heavy, local_launch, local_land, buff)
                 r0 = jax.lax.axis_index(row_axis) * rb
                 s1 = lambda x: jax.lax.dynamic_slice_in_dim(x, r0, rb,
                                                             axis=1)
@@ -422,14 +418,14 @@ class CurvatureEngine:
             else:
                 st, buf = kfactor.bucket_factor_step_async(
                     spec, st, X, keys, first, False, light, (),
-                    local_launch, (), buf, use_kernel)
+                    local_launch, (), buf)
             return self._gather_rep(st), buf
 
-        out, buf = shard_map(
+        out, buf = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(st_in, P(axis), P(axis), P(), buf_spec),
             out_specs=(st_out, buf_spec),
-            check_rep=False,
+            check_vma=False,
         )(st, X, keys, first, buf)
         return plan.unshard(out), plan.unshard(buf)
 

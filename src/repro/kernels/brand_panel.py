@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 Array = jax.Array
 
@@ -72,7 +71,7 @@ def ut_a_batched_pallas(U: Array, A: Array, bk: int = 512,
         out_specs=pl.BlockSpec((1, r, n), lambda b, k: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, r, n), U.dtype),
         scratch_shapes=[pltpu.VMEM((r, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(U, A)
@@ -96,7 +95,7 @@ def a_perp_batched_pallas(A: Array, U: Array, C: Array, bm: int = 512,
         ],
         out_specs=pl.BlockSpec((1, bm, n), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, d, n), A.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(A, U, C)

@@ -42,7 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
-from repro.kernels.tpu_compat import CompilerParams
 
 Array = jax.Array
 
@@ -85,7 +84,7 @@ def syrk_tn_batched_pallas(A: Array, bk: int = 512,
         out_specs=pl.BlockSpec((1, n, n), lambda b, k: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(A)
@@ -108,7 +107,7 @@ def rinv_apply_batched_pallas(A: Array, Rinv: Array, bm: int = 512,
         ],
         out_specs=pl.BlockSpec((1, bm, n), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, d, n), A.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(A, Rinv)

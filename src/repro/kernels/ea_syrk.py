@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 Array = jax.Array
 
@@ -80,7 +79,7 @@ def ea_syrk_batched_pallas(M: Array, X: Array, keep: Array, coef: Array,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, d, d), M.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

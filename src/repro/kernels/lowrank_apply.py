@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 Array = jax.Array
 
@@ -42,8 +41,7 @@ def _xu_kernel(x_ref, u_ref, s_ref, o_ref, acc_ref, *, n_k: int):
 
     @pl.when(k == n_k - 1)
     def _done():
-        o_ref[0] = (acc_ref[...] *
-                    s_ref[0].astype(jnp.float32)[None, :]
+        o_ref[0] = (acc_ref[...] * s_ref[0].astype(jnp.float32)
                     ).astype(o_ref.dtype)
 
 
@@ -64,11 +62,14 @@ def lowrank_apply_batched_pallas(X: Array, U: Array, s: Array, ilam: Array,
     """Y = (X U) diag(s) Uᵀ + X·ilam, batched over the leading stack axis.
 
     X: (B, p, d), U: (B, d, w), s: (B, w), ilam: (B,) (= 1/λ per element).
+    s enters the kernel as (B, 1, w) rows: a (1, w) block of a (B, w)
+    array is refused by Mosaic for B > 1 (second-minor block of 1).
     """
     B, p, d = X.shape
     w = U.shape[-1]
     bm, bn, bk = min(bm, p), min(bn, d), min(bk, d)
     ilam = jnp.reshape(ilam, (B,)).astype(jnp.float32)
+    s = jnp.reshape(s, (B, 1, w))
 
     # Stage A: T = (X U) * s  — contraction over d (no scalars needed).
     grid_a = (B, p // bm, d // bk)
@@ -78,12 +79,12 @@ def lowrank_apply_batched_pallas(X: Array, U: Array, s: Array, ilam: Array,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda b, i, k: (b, i, k)),
             pl.BlockSpec((1, bk, w), lambda b, i, k: (b, k, 0)),
-            pl.BlockSpec((1, w), lambda b, i, k: (b, 0)),
+            pl.BlockSpec((1, 1, w), lambda b, i, k: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, w), lambda b, i, k: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, p, w), X.dtype),
         scratch_shapes=[pltpu.VMEM((bm, w), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(X, U, s)
@@ -104,7 +105,7 @@ def lowrank_apply_batched_pallas(X: Array, U: Array, s: Array, ilam: Array,
                                    lambda b, i, j, *_: (b, i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, p, d), X.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(ilam, T, U, X)

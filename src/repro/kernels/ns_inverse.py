@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 Array = jax.Array
 
@@ -83,7 +82,7 @@ def gemm_update_batched_pallas(C: Array, A: Array, B: Array,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), C.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

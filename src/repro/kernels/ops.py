@@ -21,21 +21,36 @@ terms are sliced away), so padding never changes semantics.  Padding only
 engages while it is profitable: if any dim would grow beyond ``_PAD_MAX``×
 its size (tiny shapes), the op falls back to the oracle instead.
 
-Dispatch policy:
+Dispatch policy (chosen by platform):
   * TPU backend            → Pallas (compiled).
   * ``REPRO_PALLAS=interpret`` env  → Pallas interpret mode (CPU validation).
   * ``REPRO_PALLAS=off``    → oracle always.
   * otherwise (CPU/GPU)    → oracle.  CPU interpret mode is orders of
     magnitude slower than jnp and is only meant for correctness tests.
+
+``dispatch_tally()`` counts, while a program is traced inside it, which
+route each op call site took — the kernel, or the oracle and the rule
+that sent it there.
+
+Several devices: XLA cannot partition a Mosaic kernel, so a program over
+a mesh must call each one inside a ``shard_map``.  Inside
+``kernel_mesh(mesh)`` a kernel launched outside any ``shard_map`` runs
+inside one over that mesh with every operand replicated; launches that
+already sit in a ``shard_map`` body run as they are.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import functools
 import math
 import os
-from typing import Tuple
+import threading
+from typing import Dict, Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels import ea_syrk as _ea
@@ -58,11 +73,78 @@ def _mode() -> str:
         return "ref"
     if env == "interpret":
         return "interpret"
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+_TALLY = threading.local()
+
+
+@contextlib.contextmanager
+def dispatch_tally() -> Iterator[Dict[str, collections.Counter]]:
+    """Yield {op: Counter(route → call sites)} filled while tracing inside
+    the block.  Routes: ``pallas`` / ``interpret`` (the kernel), ``ref``
+    (the platform has no kernels), ``ref:pad`` (padding would grow a dim
+    past ``_PAD_MAX``), ``ref:cholqr_max_n`` (the Gram would not fit
+    VMEM), ``unfused:vmem`` (the fused stripes would not fit VMEM; two
+    ``lowrank_apply`` calls run instead and are tallied themselves)."""
+    prev = getattr(_TALLY, "counts", None)
+    counts: Dict[str, collections.Counter] = collections.defaultdict(
+        collections.Counter)
+    _TALLY.counts = counts
     try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover
-        backend = "cpu"
-    return "pallas" if backend == "tpu" else "ref"
+        yield counts
+    finally:
+        _TALLY.counts = prev
+
+
+def _note(op: str, route: str) -> None:
+    counts = getattr(_TALLY, "counts", None)
+    if counts is not None:
+        counts[op][route] += 1
+
+
+_MESH = threading.local()
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh) -> Iterator[None]:
+    """Launch the kernels traced inside the block over ``mesh``: a launch
+    outside any ``shard_map`` runs inside one whose operands and results
+    are all replicated, so every device computes the whole call — the
+    replicated math.  ``None`` or a one-device mesh changes nothing."""
+    prev = getattr(_MESH, "mesh", None)
+    _MESH.mesh = mesh if mesh is not None and mesh.size > 1 else None
+    try:
+        yield
+    finally:
+        _MESH.mesh = prev
+
+
+def _launch(kernel, *args, **static):
+    """``kernel(*args, **static)``, replicated over the ``kernel_mesh``
+    when the call is not already inside a ``shard_map`` body."""
+    call = functools.partial(kernel, **static)
+    mesh = getattr(_MESH, "mesh", None)
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return call(*args)
+    return jax.shard_map(call, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
+def _route(op: str, mode: str, fits: bool, rule: str = "pad",
+           unfused: bool = False) -> bool:
+    """Record the route of one call site; True iff it takes the kernel.
+    ``fits`` is False when the shape rule named ``rule`` sends the call
+    to the oracle; ``unfused`` marks a fused op that runs as two
+    ``lowrank_apply`` kernels instead."""
+    if mode == "ref":
+        route = "ref"
+    elif not fits:
+        route = "ref:" + rule
+    else:
+        route = "unfused:vmem" if unfused else mode
+    _note(op, route)
+    return not route.startswith("ref")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -198,7 +280,7 @@ def ea_syrk(M: Array, X: Array, rho, first) -> Array:
     M: (*stack, d, d), X: (*stack, d, n)."""
     mode = _mode()
     d, n = X.shape[-2:]
-    if mode == "ref" or not _pad_ok((d, _LANE), (n, _LANE)):
+    if not _route("ea_syrk", mode, _pad_ok((d, _LANE), (n, _LANE))):
         return ref.ea_syrk(M, X, rho, first)
     stack = _common_stack((M, 2), (X, 2))
     Xb = _flat(X, 2, stack)
@@ -211,8 +293,8 @@ def ea_syrk(M: Array, X: Array, rho, first) -> Array:
     keep = rho * (1.0 - firstf)
     coef = 1.0 - keep
     bm, bn, bk = syrk_blocks(pd, pn)
-    out = _ea.ea_syrk_batched_pallas(Mp, Xp, keep, coef, bm=bm, bn=bn, bk=bk,
-                                     interpret=(mode == "interpret"))
+    out = _launch(_ea.ea_syrk_batched_pallas, Mp, Xp, keep, coef, bm=bm,
+                  bn=bn, bk=bk, interpret=(mode == "interpret"))
     return out[..., :d, :d].reshape(stack + (d, d))
 
 
@@ -224,7 +306,7 @@ def ns_step(Mhat: Array, X: Array) -> Array:
     (2·0 − 0·0 = 0), and are sliced away."""
     mode = _mode()
     d = X.shape[-1]
-    if mode == "ref" or not _pad_ok((d, _LANE)):
+    if not _route("ns_step", mode, _pad_ok((d, _LANE))):
         return ref.ns_step(Mhat, X)
     stack = _common_stack((Mhat, 2), (X, 2))
     Mb = _flat(Mhat, 2, stack)
@@ -235,12 +317,11 @@ def ns_step(Mhat: Array, X: Array) -> Array:
     bm, bn, bk = syrk_blocks(pd, pd)
     interp = mode == "interpret"
     # T = M̂ X  (C operand rides along unused: alpha = 0)
-    T = _ns.gemm_update_batched_pallas(Xp, Mp, Xp, 0.0, 1.0,
-                                       bm=bm, bn=bn, bk=bk, interpret=interp)
+    T = _launch(_ns.gemm_update_batched_pallas, Xp, Mp, Xp, alpha=0.0,
+                beta=1.0, bm=bm, bn=bn, bk=bk, interpret=interp)
     # X' = 2X − X T
-    out = _ns.gemm_update_batched_pallas(Xp, Xp, T, 2.0, -1.0,
-                                         bm=bm, bn=bn, bk=bk,
-                                         interpret=interp)
+    out = _launch(_ns.gemm_update_batched_pallas, Xp, Xp, T, alpha=2.0,
+                  beta=-1.0, bm=bm, bn=bn, bk=bk, interpret=interp)
     return out[..., :d, :d].reshape(stack + (d, d))
 
 
@@ -250,7 +331,8 @@ def brand_panel(U: Array, A: Array):
     mode = _mode()
     d, r = U.shape[-2:]
     n = A.shape[-1]
-    if mode == "ref" or not _pad_ok((d, _LANE), (r, _SUB), (n, _LANE)):
+    if not _route("brand_panel", mode,
+                  _pad_ok((d, _LANE), (r, _SUB), (n, _LANE))):
         return ref.brand_panel(U, A)
     stack = _common_stack((U, 2), (A, 2))
     Ub = _flat(U, 2, stack)
@@ -260,10 +342,10 @@ def brand_panel(U: Array, A: Array):
     Up = _pad_tail(Ub, pd, pr)
     Ap = _pad_tail(Ab, pd, pn)
     bk = panel_blocks(pd, pr, pn)
-    C, P = _bp.brand_panel_batched_pallas(Up, Ap, bk=bk,
-                                          interpret=(mode == "interpret"))
+    C, Ap = _launch(_bp.brand_panel_batched_pallas, Up, Ap, bk=bk,
+                    interpret=(mode == "interpret"))
     return (C[..., :r, :n].reshape(stack + (r, n)),
-            P[..., :d, :n].reshape(stack + (d, n)))
+            Ap[..., :d, :n].reshape(stack + (d, n)))
 
 
 def cholqr2(A: Array) -> Tuple[Array, Array]:
@@ -278,16 +360,18 @@ def cholqr2(A: Array) -> Tuple[Array, Array]:
     """
     mode = _mode()
     d, n = A.shape[-2:]
-    if (mode == "ref" or _round_up(n, _LANE) > _CHOLQR_MAX_N
-            or not _pad_ok((d, _LANE), (n, _LANE))):
+    big = _round_up(n, _LANE) > _CHOLQR_MAX_N
+    if not _route("cholqr2", mode,
+                  not big and _pad_ok((d, _LANE), (n, _LANE)),
+                  "cholqr_max_n" if big else "pad"):
         return ref.cholqr2(A)
     stack = _common_stack((A, 2))
     Ab = _flat(A, 2, stack).astype(jnp.float32)
     pd, pn = _round_up(d, _LANE), _round_up(n, _LANE)
     Ap = _pad_tail(Ab, pd, pn)
     bk = cholqr_blocks(pd, pn)
-    Q, R = _cq.cholqr2_batched_pallas(Ap, n_true=n, bk=bk,
-                                      interpret=(mode == "interpret"))
+    Q, R = _launch(_cq.cholqr2_batched_pallas, Ap, n_true=n, bk=bk,
+                   interpret=(mode == "interpret"))
     return (Q[..., :d, :n].astype(A.dtype).reshape(stack + (d, n)),
             R[..., :n, :n].reshape(stack + (n, n)))
 
@@ -306,7 +390,8 @@ def lowrank_apply(X: Array, U: Array, s: Array, lam) -> Array:
     mode = _mode()
     p, d = X.shape[-2:]
     w = U.shape[-1]
-    if mode == "ref" or not _pad_ok((p, _LANE), (d, _LANE), (w, _SUB)):
+    if not _route("lowrank_apply", mode,
+                  _pad_ok((p, _LANE), (d, _LANE), (w, _SUB))):
         return ref.lowrank_apply(X, U, s, lam)
     stack = _common_stack((X, 2), (U, 2), (s, 1))
     Xb = _flat(X, 2, stack)
@@ -322,9 +407,8 @@ def lowrank_apply(X: Array, U: Array, s: Array, lam) -> Array:
     bm = _pick_block(pp, 256)
     bn = _pick_block(pd, 512)
     bk = _pick_block(pd, 512)
-    out = _la.lowrank_apply_batched_pallas(Xp, Up, sp, ilam, bm=bm, bn=bn,
-                                           bk=bk,
-                                           interpret=(mode == "interpret"))
+    out = _launch(_la.lowrank_apply_batched_pallas, Xp, Up, sp, ilam,
+                  bm=bm, bn=bn, bk=bk, interpret=(mode == "interpret"))
     return out[..., :p, :d].reshape(stack + (p, d))
 
 
@@ -341,13 +425,13 @@ def precond_fused(J: Array, U_g: Array, s_g: Array, lam_g,
     p, d = J.shape[-2:]
     w_g = U_g.shape[-1]
     w_a = U_a.shape[-1]
-    if mode == "ref" or not _pad_ok((p, _LANE), (d, _LANE), (w_g, _SUB),
-                                    (w_a, _SUB)):
-        return ref.precond_fused(J, U_g, s_g, lam_g, U_a, s_a, lam_a)
     pp, pd = _round_up(p, _LANE), _round_up(d, _LANE)
     pwg, pwa = _round_up(w_g, _SUB), _round_up(w_a, _SUB)
     bn = _pick_block(pd, 256)
     bm = _fused_bm(pp, pd, pwg, pwa, bn)
+    fits = _pad_ok((p, _LANE), (d, _LANE), (w_g, _SUB), (w_a, _SUB))
+    if not _route("precond_fused", mode, fits, unfused=bm is None):
+        return ref.precond_fused(J, U_g, s_g, lam_g, U_a, s_a, lam_a)
     if bm is None:
         # d too large for the J-resident stripes — stay on kernels but
         # unfused: two lowrank_apply round-trips (the pre-fusion path)
@@ -368,7 +452,7 @@ def precond_fused(J: Array, U_g: Array, s_g: Array, lam_g,
     sap = _pad_tail(sab, pwa)
     ilam_g = 1.0 / _stack_lam(lam_g, stack, b)
     ilam_a = 1.0 / _stack_lam(lam_a, stack, b)
-    out = _pf.precond_fused_pallas(Jp, Ugp, sgp, ilam_g, Uap, sap, ilam_a,
-                                   bm=bm, bn=bn,
-                                   interpret=(mode == "interpret"))
+    out = _launch(_pf.precond_fused_pallas, Jp, Ugp, sgp, ilam_g, Uap,
+                  sap, ilam_a, bm=bm, bn=bn,
+                  interpret=(mode == "interpret"))
     return out[..., :p, :d].reshape(stack + (p, d))

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 Array = jax.Array
 
@@ -56,8 +55,8 @@ def _panel_kernel(ug_ref, j_ref, sg_ref, o_ref, acc_ref, *, n_i: int):
 
     @pl.when(i == n_i - 1)
     def _done():
-        sg = sg_ref[0].astype(jnp.float32)
-        o_ref[0] = (sg[:, None] * acc_ref[...]).astype(o_ref.dtype)
+        sg = sg_ref[0].astype(jnp.float32)               # (w_g, 1) column
+        o_ref[0] = (sg * acc_ref[...]).astype(o_ref.dtype)
 
 
 def _apply_kernel(ilam_g_ref, ilam_a_ref, j_ref, ug_ref, cg_ref, ua_ref,
@@ -88,8 +87,8 @@ def _apply_kernel(ilam_g_ref, ilam_a_ref, j_ref, ug_ref, cg_ref, ua_ref,
 
     @pl.when(t == 1)
     def _sweep_out():
-        sa = sa_ref[0].astype(jnp.float32)
-        tw = tw_ref[...] * sa[None, :]
+        sa = sa_ref[0].astype(jnp.float32)               # (1, w_a) row
+        tw = tw_ref[...] * sa
         w_blk = w_ref[:, pl.ds(j * bn, bn)]
         acc = jax.lax.dot_general(
             tw, ua_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
@@ -108,6 +107,11 @@ def precond_fused_pallas(J: Array, U_g: Array, s_g: Array, ilam_g: Array,
     J: (B, p, d), U_g: (B, p, w_g), s_g: (B, w_g), ilam_g: (B,),
     U_a: (B, d, w_a), s_a: (B, w_a), ilam_a: (B,).
     Requires p % bm == 0 and d % bn == 0 (ops.py pads / falls back).
+
+    s_g and s_a enter the kernels as (B, w_g, 1) columns and (B, 1, w_a)
+    rows: a (1, w) block of a (B, w) array would put the stack axis in the
+    second-minor position with block size 1, which Mosaic refuses for
+    B > 1 (neither 8-aligned nor the full dimension).
     """
     B, p, d = J.shape
     w_g = U_g.shape[-1]
@@ -115,6 +119,8 @@ def precond_fused_pallas(J: Array, U_g: Array, s_g: Array, ilam_g: Array,
     bm, bn = min(bm, p), min(bn, d)
     ilam_g = jnp.reshape(ilam_g, (B,)).astype(jnp.float32)
     ilam_a = jnp.reshape(ilam_a, (B,)).astype(jnp.float32)
+    s_g = jnp.reshape(s_g, (B, w_g, 1))
+    s_a = jnp.reshape(s_a, (B, 1, w_a))
 
     # Launch 1 — rank panel Cg = diag(s_g) U_gᵀ J, contraction over p
     # (no damping scalars involved).
@@ -125,12 +131,12 @@ def precond_fused_pallas(J: Array, U_g: Array, s_g: Array, ilam_g: Array,
         in_specs=[
             pl.BlockSpec((1, bm, w_g), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, bm, bn), lambda b, j, i: (b, i, j)),
-            pl.BlockSpec((1, w_g), lambda b, j, i: (b, 0)),
+            pl.BlockSpec((1, w_g, 1), lambda b, j, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, w_g, bn), lambda b, j, i: (b, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, w_g, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((w_g, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(U_g, J, s_g)
@@ -147,7 +153,8 @@ def precond_fused_pallas(J: Array, U_g: Array, s_g: Array, ilam_g: Array,
                 pl.BlockSpec((1, bm, w_g), lambda b, i, t, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, w_g, bn), lambda b, i, t, j, *_: (b, 0, j)),
                 pl.BlockSpec((1, bn, w_a), lambda b, i, t, j, *_: (b, j, 0)),
-                pl.BlockSpec((1, w_a), lambda b, i, t, j, *_: (b, 0)),
+                pl.BlockSpec((1, 1, w_a),
+                             lambda b, i, t, j, *_: (b, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, bm, bn),
                                    lambda b, i, t, j, *_: (b, i, j)),
@@ -157,7 +164,7 @@ def precond_fused_pallas(J: Array, U_g: Array, s_g: Array, ilam_g: Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, p, d), J.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,

@@ -13,12 +13,18 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (elastic fallback shapes, tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (elastic fallback shapes, tests).
+
+    Axes are ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    whose sharding-in-types rejects the plain gathers of the embedding
+    lookup and the curvature engine's slot (un)permutation."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

@@ -37,8 +37,8 @@ def shard_policy_for(mesh: Optional[Mesh], shard_kv_seq: bool = False,
                        shard_kv_seq=shard_kv_seq, axis_sizes=sizes)
 
 
-def default_kfac_config(arch: ArchConfig, variant: str = "bkfac",
-                        use_kernels: bool = False) -> kfac_lib.KfacConfig:
+def default_kfac_config(arch: ArchConfig, variant: str = "bkfac"
+                        ) -> kfac_lib.KfacConfig:
     pol = policy_lib.PolicyConfig(variant=variant, r=256,
                                   max_dense_dim=8192)
     return kfac_lib.KfacConfig(
@@ -46,7 +46,6 @@ def default_kfac_config(arch: ArchConfig, variant: str = "bkfac",
         lr=optbase.constant(0.3),
         damping_phi=optbase.constant(0.1),
         weight_decay=7e-4, clip=0.07,
-        use_kernels=use_kernels,
         T_updt=25, T_inv=250, T_brand=25, T_rsvd=250, T_corct=500,
         fallback_lr=optbase.constant(1e-3))
 

@@ -27,6 +27,7 @@ from repro.core import policy as policy_lib
 from repro.data.synthetic import TokenStream
 from repro.distributed import compress as compress_lib
 from repro.distributed import sharding as shd
+from repro.launch import compile_cache
 from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_production_mesh, make_mesh
 from repro.models import layers
@@ -107,6 +108,7 @@ def main():
     ap.add_argument("--profile-steps", type=int, default=3,
                     help="steps in the --profile-dir trace window")
     args = ap.parse_args()
+    compile_cache.enable_compile_cache()
 
     jsonl = (os.path.join(args.telemetry_dir, "events.jsonl")
              if args.telemetry_dir else None)

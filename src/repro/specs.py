@@ -64,8 +64,11 @@ class DistSpec:
         return self.mesh is not None and self.curvature_axis is not None
 
     def attach(self, opt) -> Optional[Any]:
-        """Build + attach the curvature engine for ``opt`` (a Kfac); a
-        no-op returning None when no mesh/axis is configured."""
+        """Give ``opt`` (a Kfac) the mesh its step runs over, and build +
+        attach the curvature engine; returns None, attaching no engine,
+        when no mesh/axis is configured."""
+        if self.mesh is not None:
+            opt.mesh = self.mesh
         if not self.active:
             return None
         from repro.distributed import curvature as curvature_lib

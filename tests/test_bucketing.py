@@ -41,12 +41,12 @@ def _data(taps, key=None):
 
 
 def _run(taps, variant, bucketed, steps=2, heavy_every=2, r=8,
-         max_dense_dim=8192, use_kernels=False, momentum=0.9):
+         max_dense_dim=8192, momentum=0.9):
     pol = policy.PolicyConfig(variant=variant, r=r,
                               max_dense_dim=max_dense_dim)
     cfg = kfac_lib.KfacConfig(policy=pol, lr=optbase.constant(0.05),
                               momentum=momentum, T_updt=1, T_brand=1,
-                              bucketed=bucketed, use_kernels=use_kernels)
+                              bucketed=bucketed)
     opt = kfac_lib.Kfac(cfg, taps)
     params, grads, acts, pgs = _data(taps)
     st = opt.init(params)
@@ -209,11 +209,12 @@ def test_bucketed_randomized_heavy_modes_run():
 
 @pytest.mark.slow
 def test_bucketed_kernel_path_matches_jnp(monkeypatch):
-    """Bucketed + use_kernels (interpret) ≡ bucketed jnp oracles, end to
-    end on the mixed model — the acceptance gate of the PR."""
-    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    """Bucketed through the Pallas kernels (interpret) ≡ bucketed through
+    the jnp oracles, end to end on the mixed model."""
     taps = _mixed_taps()
-    _, a = _run(taps, "bkfac", bucketed=True, use_kernels=True, steps=2)
-    _, b = _run(taps, "bkfac", bucketed=True, use_kernels=False, steps=2)
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    _, a = _run(taps, "bkfac", bucketed=True, steps=2)
+    monkeypatch.setenv("REPRO_PALLAS", "off")
+    _, b = _run(taps, "bkfac", bucketed=True, steps=2)
     for ua, ub in zip(a, b):
         _assert_updates_close(ua, ub, taps, atol=2e-3)

@@ -1,7 +1,6 @@
 """CholeskyQR2-style tall-skinny QR: algebraic properties of the oracle
 and interpret-mode parity of the Pallas kernel pair (SYRK + root-apply)
-against it, plus the Brand-update wiring
-(`sym_brand_update(use_kernel=True)`).
+against it, plus the Brand-update wiring (`sym_brand_update`).
 
 Property tolerances are driven by the algorithm: two passes of the
 clamped spectral root give ‖QᵀQ − I‖ ≈ machine-eps on full-rank panels,
@@ -168,19 +167,33 @@ def test_tiny_panel_falls_back_to_oracle(interpret_mode):
 # Brand-update wiring
 # ---------------------------------------------------------------------------
 
+def _householder_sym_brand(U, D, A):
+    """Paper Alg 3 with Householder QR of A⊥ — the reference the
+    CholeskyQR2 light update is checked against."""
+    C = jnp.swapaxes(U, -1, -2) @ A
+    Q, R = jnp.linalg.qr(A - U @ C)
+    mt = lambda x: jnp.swapaxes(x, -1, -2)
+    top = jnp.concatenate([D[..., None, :] * jnp.eye(D.shape[-1])
+                           + C @ mt(C), C @ mt(R)], axis=-1)
+    bot = jnp.concatenate([R @ mt(C), R @ mt(R)], axis=-1)
+    Dm, Wm = jnp.linalg.eigh(jnp.concatenate([top, bot], axis=-2))
+    return (jnp.concatenate([U, Q], axis=-1) @ Wm[..., :, ::-1],
+            Dm[..., ::-1])
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("stack", [(), (3,)])  # CI kernel-parity runs both
 def test_sym_brand_update_kernel_path_matches_jnp(interpret_mode, stack):
-    """use_kernel=True (Pallas panel + CholeskyQR2) and the default
-    Householder path represent the same matrix and spectrum."""
+    """The kernel path (Pallas panel + CholeskyQR2) and a Householder-QR
+    Brand update represent the same matrix and spectrum."""
     d, r, n = 256, 16, 32
     ks = jax.random.split(jax.random.PRNGKey(6), 3)
     U = jnp.linalg.qr(jax.random.normal(ks[0], stack + (d, r)))[0]
     D = jnp.sort(jax.random.uniform(ks[1], stack + (r,), minval=0.1,
                                     maxval=2.0), axis=-1)[..., ::-1]
     A = jax.random.normal(ks[2], stack + (d, n))
-    U1, D1 = brand.sym_brand_update(U, D, A, use_kernel=False)
-    U2, D2 = brand.sym_brand_update(U, D, A, use_kernel=True)
+    U1, D1 = _householder_sym_brand(U, D, A)
+    U2, D2 = brand.sym_brand_update(U, D, A)
     np.testing.assert_allclose(np.asarray(D1), np.asarray(D2),
                                rtol=1e-3, atol=1e-3)
     rec1 = (U1 * D1[..., None, :]) @ jnp.swapaxes(U1, -1, -2)

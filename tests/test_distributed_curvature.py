@@ -14,10 +14,13 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import specs
 from repro.core import buckets, kfac as kfac_lib, policy
 from synthdata import tap_data
 from repro.distributed import curvature as curv
+from repro.kernels import ops
 from repro.launch import mesh as mesh_lib
 from repro.optim import base as optbase
 
@@ -108,7 +111,10 @@ class TestShardPlan:
 # sharded ≡ replicated parity (8-device host mesh)
 # ---------------------------------------------------------------------------
 
-def _run(taps, variant, *, sharded, stagger=False, steps=4):
+def _run(taps, variant, *, sharded, stagger=False, steps=4,
+         data_mesh=False):
+    """``data_mesh``: no engine, but the step runs over an 8-device
+    ("data",) mesh with the stats rows sharded over it."""
     pol = policy.PolicyConfig(variant=variant, r=8, max_dense_dim=8192)
     cfg = kfac_lib.KfacConfig(policy=pol, lr=optbase.constant(0.05),
                               momentum=0.9, T_updt=1, T_brand=1, T_inv=3,
@@ -122,6 +128,13 @@ def _run(taps, variant, *, sharded, stagger=False, steps=4):
     # engine-attached scheduler would pick align=8 automatically)
     sched = opt.scheduler(align=8)
     params, grads, acts, pgs = _data(taps)
+    if data_mesh:
+        mesh = mesh_lib.make_mesh((8,), ("data",))
+        specs.DistSpec(mesh=mesh).attach(opt)
+        rows = lambda x: jax.device_put(x, NamedSharding(
+            mesh, P(*([None] * (x.ndim - 2)), "data", None)))
+        acts = jax.tree_util.tree_map(rows, acts)
+        pgs = jax.tree_util.tree_map(rows, pgs)
     st = opt.init(params)
 
     def step(grads, st, rng, work):
@@ -272,3 +285,24 @@ def test_sharded_under_mesh_context_with_shardings():
     taps = _mixed_taps()
     a, _ = _run(taps, "bkfac", sharded=True, steps=2)
     assert all(np.isfinite(np.asarray(u["fc"]["w"])).all() for u in a)
+
+
+def test_kernels_on_data_mesh_without_engine_match_replicated(monkeypatch):
+    """With no curvature engine, a step over a data-parallel mesh launches
+    every kernel replicated over it (``ops.kernel_mesh``): the same
+    updates as one device, the stats rows gathered for the kernels."""
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 host devices")
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    # widths the kernels take without padding past 2× (d=128 sides are
+    # Brand, d=64 sides dense EVD)
+    taps = {"fc": kfac_lib.TapInfo("fc/w", 128, 64, n_stat=64),
+            "scan": kfac_lib.TapInfo("scan/w", 128, 128, stack=(2,),
+                                     n_stat=64)}
+    with ops.dispatch_tally() as tally:
+        a, _ = _run(taps, "bkfac", sharded=False, steps=2, data_mesh=True)
+    for op in ("ea_syrk", "brand_panel", "cholqr2", "precond_fused"):
+        assert tally[op]["interpret"] > 0, (op, dict(tally[op]))
+    b, _ = _run(taps, "bkfac", sharded=False, steps=2)
+    for ua, ub in zip(a, b):
+        _assert_close(ua, ub, taps, atol=1e-5)

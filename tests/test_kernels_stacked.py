@@ -162,10 +162,9 @@ def test_precond_fused_matches_two_sided_composition(interpret_mode):
     D_g = jnp.sort(jax.random.uniform(ks[4], (w,), minval=0.05,
                                       maxval=3.0))[::-1]
     lam_a, lam_g = jnp.asarray(0.4), jnp.asarray(0.7)
-    got = precond.kfac_precondition(J, U_g, D_g, lam_g, U_a, D_a, lam_a,
-                                    use_kernel=True)
-    want = precond.kfac_precondition(J, U_g, D_g, lam_g, U_a, D_a, lam_a,
-                                     use_kernel=False)
+    got = precond.kfac_precondition(J, U_g, D_g, lam_g, U_a, D_a, lam_a)
+    want = precond.apply_inv_left(
+        precond.apply_inv_right(J, U_a, D_a, lam_a), U_g, D_g, lam_g)
     _close(got, want, jnp.float32)
 
 
@@ -208,8 +207,9 @@ def test_tiny_shapes_fall_back_to_ref(interpret_mode):
 
 
 @pytest.mark.slow
-def test_stacked_optimizer_update_kernels_match_jnp(interpret_mode):
-    """End to end: a stacked tap steps identically with use_kernels on/off."""
+def test_stacked_optimizer_update_kernels_match_jnp(monkeypatch):
+    """End to end: a stacked tap steps identically through the Pallas
+    kernels (interpret) and through the jnp oracles."""
     from repro.core import kfac as kfac_lib
     from repro.core import policy
     from repro.optim import base as optbase
@@ -225,9 +225,10 @@ def test_stacked_optimizer_update_kernels_match_jnp(interpret_mode):
     pgs = {"blk": jax.random.normal(jax.random.fold_in(key, 3),
                                     (L, N, D)) * 1e-3}
 
-    def run(use_k):
+    def run(pallas):
+        monkeypatch.setenv("REPRO_PALLAS", pallas)
         cfg = kfac_lib.KfacConfig(policy=pol, lr=optbase.constant(0.05),
-                                  T_updt=1, T_brand=1, use_kernels=use_k)
+                                  T_updt=1, T_brand=1)
         opt = kfac_lib.Kfac(cfg, taps)
         st = opt.init(params)
         for step in range(1):
@@ -237,6 +238,6 @@ def test_stacked_optimizer_update_kernels_match_jnp(interpret_mode):
                                  work=opt.uniform_work(True, True, False))
         return upd["blk"]["w"]
 
-    a, b = run(False), run(True)
+    a, b = run("off"), run("interpret")
     assert np.isfinite(np.asarray(a)).all()
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
