@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from repro.kernels.ref import mt as _mt
+from repro.obs import trace as obs_trace
 
 Array = jax.Array
 
@@ -98,14 +99,18 @@ def sym_brand_update(U: Array, D: Array, A: Array) -> Tuple[Array, Array]:
     launches (``brand_panel`` + CholeskyQR2), so the whole light update is
     linear in d with no XLA QR left.
     """
-    C, A_perp = kops.brand_panel(U, A)               # (…, r, n), (…, d, n)
-    Q, R = kops.cholqr2(A_perp)                      # (…, d, n), (…, n, n)
-    top = jnp.concatenate([_batched_diag(D) + C @ _mt(C), C @ _mt(R)],
-                          axis=-1)
-    bot = jnp.concatenate([R @ _mt(C), R @ _mt(R)], axis=-1)
-    Ms = jnp.concatenate([top, bot], axis=-2)        # (…, r+n, r+n)
-    Dm, Wm = _desc_eigh(Ms)
-    U_new = jnp.concatenate([U, Q], axis=-1) @ Wm    # (…, d, r+n)
+    with obs_trace.span("brand_panel"):
+        C, A_perp = kops.brand_panel(U, A)           # (…, r, n), (…, d, n)
+    with obs_trace.span("brand_qr"):
+        Q, R = kops.cholqr2(A_perp)                  # (…, d, n), (…, n, n)
+    with obs_trace.span("brand_core"):
+        top = jnp.concatenate([_batched_diag(D) + C @ _mt(C), C @ _mt(R)],
+                              axis=-1)
+        bot = jnp.concatenate([R @ _mt(C), R @ _mt(R)], axis=-1)
+        Ms = jnp.concatenate([top, bot], axis=-2)    # (…, r+n, r+n)
+        Dm, Wm = _desc_eigh(Ms)
+    with obs_trace.span("brand_rotate"):
+        U_new = jnp.concatenate([U, Q], axis=-1) @ Wm    # (…, d, r+n)
     return U_new, Dm
 
 

@@ -290,78 +290,85 @@ def run_steps(args, sched, det, stream, step_fn, state, checkpointer,
     k_off = 0          # rollback re-anchor: schedule runs at k_off + k
     for k in range(k0, args.steps):
         last_k = k
-        t0 = time.time()
-        kk = k_off + k
-        work = sched.work(kk)
-        if policy is not None and policy.take_refresh():
-            # remediation stage 2: abandon the (possibly poisoned)
-            # pipeline, re-establish the inverse rep from the live M
-            work = opt.remedial_work()
-            state = state._replace(opt=opt.clear_inflight(state.opt))
-            if runner is not None:
-                runner.drop_pending(reason="dropped")
-        actions = det.observe_step(k, {"host0": time.time() - t0 + 1e-6})
-        work = strag_lib.apply_to_work(actions.get("host0",
-                                                   strag_lib.Action.NONE),
-                                       work)
-        batch = stream.batch_at(k)
-        landing = (runner.landing(work, step=kk)
-                   if runner is not None else None)
         if profiler is not None:
             profiler.tick(k)
-        report = None
-        if policy is not None:
-            scale = jnp.float32(policy.damping_scale)
-            if meter is None:
-                state, loss, report = step_fn(state, batch, work,
-                                              landing, None, scale)
-            else:
-                state, loss, report, mbuf = step_fn(state, batch, work,
-                                                    landing, mbuf, scale)
-        elif cstate is not None:
-            # compressed-DP step: the CompressState carry trails the
-            # outputs (after mbuf when a meter is on)
-            if meter is None:
-                state, loss, cstate = step_fn(state, batch, work, landing,
-                                              None, cstate)
-            else:
-                state, loss, mbuf, cstate = step_fn(state, batch, work,
-                                                    landing, mbuf, cstate)
-        elif meter is None:
-            state, loss = step_fn(state, batch, work, landing)
-        else:
-            state, loss, mbuf = step_fn(state, batch, work, landing, mbuf)
-        if runner is not None:
-            runner.launch(state.opt, work, step=kk)
-        losses.append(float(loss))
-        faulty = False
-        if policy is not None:
-            rep = {n: float(v) for n, v in
-                   jax.device_get(report).items()}
-            faulty = policy.observe(kk, losses[-1], rep)
-            if policy.take_rollback() and args.ckpt_dir:
-                # remediation stage 3: restore the newest snapshot that
-                # verifies and re-anchor the staggered cadence on it
+        batch = stream.batch_at(k)
+        t0 = time.time()
+        kk = k_off + k
+        with obs_trace.host_span(obs_trace.SCHEDULE):
+            work = sched.work(kk)
+            if policy is not None and policy.take_refresh():
+                # remediation stage 2: abandon the (possibly poisoned)
+                # pipeline, re-establish the inverse rep from the live M
+                work = opt.remedial_work()
+                state = state._replace(opt=opt.clear_inflight(state.opt))
                 if runner is not None:
                     runner.drop_pending(reason="dropped")
-                if checkpointer is not None:
-                    checkpointer.wait()
-                state, man = ckpt.restore_latest_healthy(args.ckpt_dir,
-                                                         state)
-                k_off = int(jax.device_get(state.opt.phase)) - (k + 1)
-                policy.notify_rollback(kk, man["step"], args.ckpt_dir)
+            actions = det.observe_step(k, {"host0": time.time() - t0
+                                           + 1e-6})
+            work = strag_lib.apply_to_work(
+                actions.get("host0", strag_lib.Action.NONE), work)
+            landing = (runner.landing(work, step=kk)
+                       if runner is not None else None)
+        report = None
+        scale = jnp.float32(policy.damping_scale) \
+            if policy is not None else None
+        with obs_trace.host_span(obs_trace.DISPATCH):
+            if policy is not None:
+                if meter is None:
+                    state, loss, report = step_fn(state, batch, work,
+                                                  landing, None, scale)
+                else:
+                    state, loss, report, mbuf = step_fn(
+                        state, batch, work, landing, mbuf, scale)
+            elif cstate is not None:
+                # compressed-DP step: the CompressState carry trails the
+                # outputs (after mbuf when a meter is on)
+                if meter is None:
+                    state, loss, cstate = step_fn(state, batch, work,
+                                                  landing, None, cstate)
+                else:
+                    state, loss, mbuf, cstate = step_fn(
+                        state, batch, work, landing, mbuf, cstate)
+            elif meter is None:
+                state, loss = step_fn(state, batch, work, landing)
+            else:
+                state, loss, mbuf = step_fn(state, batch, work, landing,
+                                            mbuf)
+        if runner is not None:
+            runner.launch(state.opt, work, step=kk)
+        with obs_trace.host_span(obs_trace.LOSS_SYNC):
+            losses.append(float(loss))
+        # the step's bookkeeping is this loop's per-step hook
+        with obs_trace.host_span(obs_trace.CALLBACK):
+            faulty = False
+            if policy is not None:
+                rep = {n: float(v) for n, v in
+                       jax.device_get(report).items()}
+                faulty = policy.observe(kk, losses[-1], rep)
+                if policy.take_rollback() and args.ckpt_dir:
+                    # remediation stage 3: restore the newest snapshot
+                    # that verifies and re-anchor the staggered cadence
+                    if runner is not None:
+                        runner.drop_pending(reason="dropped")
+                    if checkpointer is not None:
+                        checkpointer.wait()
+                    state, man = ckpt.restore_latest_healthy(
+                        args.ckpt_dir, state)
+                    k_off = int(jax.device_get(state.opt.phase)) - (k + 1)
+                    policy.notify_rollback(kk, man["step"], args.ckpt_dir)
+                    if writer is not None:
+                        writer.emit("ckpt_restore", step=int(man["step"]),
+                                    path=args.ckpt_dir)
+                    faulty = False
+            if (checkpointer is not None and not faulty
+                    and k % args.ckpt_every == 0):
+                checkpointer.submit(k, state)
                 if writer is not None:
-                    writer.emit("ckpt_restore", step=int(man["step"]),
-                                path=args.ckpt_dir)
-                faulty = False
-        if (checkpointer is not None and not faulty
-                and k % args.ckpt_every == 0):
-            checkpointer.submit(k, state)
+                    writer.emit("ckpt_save", step=k, path=args.ckpt_dir)
             if writer is not None:
-                writer.emit("ckpt_save", step=k, path=args.ckpt_dir)
-        if writer is not None:
-            writer.emit("step", step=kk, loss=float(loss),
-                        dt_s=time.time() - t0, phase=work.label)
+                writer.emit("step", step=kk, loss=float(loss),
+                            dt_s=time.time() - t0, phase=work.label)
     if meter is not None:
         meter.drain(mbuf, last_k)
     return state

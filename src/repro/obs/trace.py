@@ -8,9 +8,22 @@ Two kinds of annotation, matching where the code runs:
     and in dumped HLO — under a readable ``kfac/...`` path instead of a
     fusion soup.
   * :func:`host_span` — ``jax.profiler.TraceAnnotation`` for *host*
-    code (the AsyncInverseRunner's worker thread, checkpoint IO), which
-    emits a real TraceMe at runtime so overlap is visible on the
-    profile's host track.
+    code (the training loops' per-step phases below, the
+    AsyncInverseRunner's worker thread), which emits a real TraceMe at
+    runtime on the profile's host track, on the same clock as the
+    device's ops.
+
+Device scopes on the training step: ``model`` (the loss, so forward ops
+read ``jvp(model)/...`` and backward ops ``transpose(jvp(model))/...``),
+``update`` (the optimizer's update and its application; the K-FAC work
+inside it reads ``update/kfac/factor/...`` and ``update/kfac/precond/...``)
+and, inside a Brand light update, ``brand_panel``, ``brand_qr``,
+``brand_core`` and ``brand_rotate`` (arXiv:2210.08494, Alg. 3).
+
+Host spans of one training-loop iteration, in order: :data:`SCHEDULE`
+(the step's work mask, remediation and async landings), :data:`DISPATCH`
+(the step call), :data:`LOSS_SYNC` (the wait for the step's loss) and
+:data:`CALLBACK` (the caller's per-step hook).
 
 :class:`StepProfiler` drives ``--profile-dir``: capture a profiler
 trace for a contiguous window of training steps (skipping step 0 by
@@ -22,6 +35,13 @@ import contextlib
 from typing import Optional
 
 import jax
+
+#: host spans of one training-loop iteration (``train/loop.py``,
+#: ``launch/train.py``), in the order they run
+SCHEDULE = "train/schedule"
+DISPATCH = "train/dispatch"
+LOSS_SYNC = "train/loss_sync"
+CALLBACK = "train/callback"
 
 
 @contextlib.contextmanager

@@ -10,7 +10,7 @@ co-dependent scalars:
 
   * :class:`DistSpec`        mesh / curvature_axis / row_axis /
                              curvature_compress  (docs/distributed.md)
-  * :class:`ObsSpec`         writer / metrics_every / profile knobs
+  * :class:`ObsSpec`         writer / metrics_every
                              (docs/observability.md)
   * :class:`CkptSpec`        ckpt dir / cadence / retention
   * :class:`ResilienceSpec`  health guards / remediation policy / chaos
@@ -80,12 +80,9 @@ class DistSpec:
 @dataclasses.dataclass(frozen=True)
 class ObsSpec:
     """Observability spec: the run's telemetry writer plus the in-graph
-    metrics cadence (``metrics_every`` steps per flush window; 0 = off)
-    and optional profiler-trace knobs."""
+    metrics cadence (``metrics_every`` steps per flush window; 0 = off)."""
     writer: Any = None                  # repro.obs.TelemetryWriter
     metrics_every: int = 0
-    profile_dir: Optional[str] = None
-    profile_steps: int = 3
 
     def make_meter(self, opt) -> Optional[Any]:
         """An in-graph curvature Meter flushing to ``writer`` every

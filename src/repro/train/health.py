@@ -61,6 +61,7 @@ import jax.numpy as jnp
 from repro.core import kfactor
 from repro.models import layers
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.optim import base as optbase
 
 Array = jax.Array
@@ -210,17 +211,19 @@ def make_resilient_kfac_step(loss_fn, opt, n_tokens: int,
             loss_fn, state.params, probes, batch)
 
         def body():
-            updates, opt_state = opt.update(
-                gp, state.opt, state.params, acts=acts,
-                probe_grads=gprobe, n_tokens=n_tokens, rng=sub,
-                work=work, landing=landing, damping_scale=damping_scale)
-            report = health_report(hcfg, opt, loss, gp, updates,
-                                   opt_state)
-            _record_health(report)
-            ok = report["ok"] > 0
-            params = optbase.apply_updates(state.params, updates)
-            params = _select(ok, params, state.params)
-            opt_state = _select(ok, opt_state, state.opt)
+            with obs_trace.span("update"):
+                updates, opt_state = opt.update(
+                    gp, state.opt, state.params, acts=acts,
+                    probe_grads=gprobe, n_tokens=n_tokens, rng=sub,
+                    work=work, landing=landing,
+                    damping_scale=damping_scale)
+                report = health_report(hcfg, opt, loss, gp, updates,
+                                       opt_state)
+                _record_health(report)
+                ok = report["ok"] > 0
+                params = optbase.apply_updates(state.params, updates)
+                params = _select(ok, params, state.params)
+                opt_state = _select(ok, opt_state, state.opt)
             return params, opt_state, report
 
         if meter is None:

@@ -48,8 +48,20 @@ def kfac_grads(loss_fn, params, probes, batch, rng=None):
     """(loss, acts), grads w.r.t. params AND probes, one backward pass."""
     args = (params, probes, batch) + ((rng,) if rng is not None else ())
     (loss, acts), (gp, gprobe) = jax.value_and_grad(
-        loss_fn, argnums=(0, 1), has_aux=True)(*args)
+        _model(loss_fn), argnums=(0, 1), has_aux=True)(*args)
     return loss, acts, gp, gprobe
+
+
+def _model(loss_fn):
+    """``loss_fn`` under the ``model`` scope, for differentiating: its
+    forward ops then read ``jvp(model)/...`` and its backward ops
+    ``transpose(jvp(model))/...`` in a profile."""
+
+    def model(*args):
+        with obs_trace.span("model"):
+            return loss_fn(*args)
+
+    return model
 
 
 def make_kfac_step(loss_fn: Callable, opt: kfac_lib.Kfac,
@@ -117,20 +129,21 @@ def make_scheduled_kfac_step(loss_fn: Callable, opt: kfac_lib.Kfac,
                                             batch)
         if grad_transform is not None:
             gp, cstate = grad_transform(gp, cstate)
-        if meter is None:
-            updates, opt_state = opt.update(
-                gp, state.opt, state.params, acts=acts,
-                probe_grads=gprobe, n_tokens=n_tokens, rng=sub, work=work,
-                landing=landing)
-        else:
-            with meter.collecting() as col:
+        with obs_trace.span("update"):
+            if meter is None:
                 updates, opt_state = opt.update(
                     gp, state.opt, state.params, acts=acts,
                     probe_grads=gprobe, n_tokens=n_tokens, rng=sub,
                     work=work, landing=landing)
-            mbuf = meter.maybe_flush(meter.merge(mbuf, col),
-                                     opt_state.step)
-        params = optbase.apply_updates(state.params, updates)
+            else:
+                with meter.collecting() as col:
+                    updates, opt_state = opt.update(
+                        gp, state.opt, state.params, acts=acts,
+                        probe_grads=gprobe, n_tokens=n_tokens, rng=sub,
+                        work=work, landing=landing)
+                mbuf = meter.maybe_flush(meter.merge(mbuf, col),
+                                         opt_state.step)
+            params = optbase.apply_updates(state.params, updates)
         out = TrainState(params=params, opt=opt_state, rng=rng)
         outs = (out, loss)
         if meter is not None:
@@ -332,10 +345,11 @@ def make_baseline_step(loss_fn: Callable, opt: optbase.Optimizer):
     def step(state: TrainState, batch):
         rng, _ = jax.random.split(state.rng)
         probes = {}
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, _), grads = jax.value_and_grad(_model(loss_fn), has_aux=True)(
             state.params, probes, batch)
-        updates, opt_state = opt.update(grads, state.opt, state.params)
-        params = optbase.apply_updates(state.params, updates)
+        with obs_trace.span("update"):
+            updates, opt_state = opt.update(grads, state.opt, state.params)
+            params = optbase.apply_updates(state.params, updates)
         return TrainState(params=params, opt=opt_state, rng=rng), loss
 
     return step
@@ -445,35 +459,40 @@ def run_kfac_training(loss_fn, opt: kfac_lib.Kfac, params, batches,
             chaos.check(k)                        # host_loss raises here
             batch = chaos.corrupt_batch(k, batch)
             state = chaos.corrupt_state(k, state)
-        work = sched.work(kk)
-        if policy is not None and policy.take_refresh():
-            # Stage 2: abandon the (possibly poisoned) pipeline and
-            # re-establish the inverse rep from the live M this step.
-            work = opt.remedial_work()
-            state = state._replace(opt=opt.clear_inflight(state.opt))
-            if runner is not None:
-                runner.drop_pending(reason="dropped")
-        if runner is not None and chaos is not None:
-            chaos.harass_runner(k, runner)
-        landing = runner.landing(work, step=kk) \
-            if runner is not None else None
+        with obs_trace.host_span(obs_trace.SCHEDULE):
+            work = sched.work(kk)
+            if policy is not None and policy.take_refresh():
+                # Stage 2: abandon the (possibly poisoned) pipeline and
+                # re-establish the inverse rep from the live M this step.
+                work = opt.remedial_work()
+                state = state._replace(opt=opt.clear_inflight(state.opt))
+                if runner is not None:
+                    runner.drop_pending(reason="dropped")
+            if runner is not None and chaos is not None:
+                chaos.harass_runner(k, runner)
+            landing = runner.landing(work, step=kk) \
+                if runner is not None else None
         t0 = time.perf_counter()
         report = None
-        if policy is not None:
-            scale = jnp.float32(policy.damping_scale)
-            if meter is None:
-                state, loss, report = step_fn(state, batch, work, landing,
-                                              None, scale)
+        scale = jnp.float32(policy.damping_scale) \
+            if policy is not None else None
+        with obs_trace.host_span(obs_trace.DISPATCH):
+            if policy is not None:
+                if meter is None:
+                    state, loss, report = step_fn(state, batch, work,
+                                                  landing, None, scale)
+                else:
+                    state, loss, report, mbuf = step_fn(
+                        state, batch, work, landing, mbuf, scale)
+            elif meter is None:
+                state, loss = step_fn(state, batch, work, landing)
             else:
-                state, loss, report, mbuf = step_fn(state, batch, work,
-                                                    landing, mbuf, scale)
-        elif meter is None:
-            state, loss = step_fn(state, batch, work, landing)
-        else:
-            state, loss, mbuf = step_fn(state, batch, work, landing, mbuf)
+                state, loss, mbuf = step_fn(state, batch, work, landing,
+                                            mbuf)
         if runner is not None:
             runner.launch(state.opt, work, step=kk)
-        losses.append(float(loss))
+        with obs_trace.host_span(obs_trace.LOSS_SYNC):
+            losses.append(float(loss))
         if writer is not None:
             writer.emit("step", step=kk, loss=float(loss),
                         dt_s=time.perf_counter() - t0, phase=work.label)
@@ -506,7 +525,8 @@ def run_kfac_training(loss_fn, opt: kfac_lib.Kfac, params, batches,
             if chaos is not None:
                 chaos.corrupt_ckpt(k, ckpt_dir)
         if callback is not None:
-            callback(k, state, loss)
+            with obs_trace.host_span(obs_trace.CALLBACK):
+                callback(k, state, loss)
     if meter is not None:
         meter.drain(mbuf, int(jax.device_get(state.opt.step)))
     if runner is not None:
