@@ -34,6 +34,10 @@ EVENT_TYPES: Dict[str, frozenset] = {
     "log": frozenset({"msg"}),
     # training
     "step": frozenset({"step", "loss", "dt_s", "phase"}),
+    # what each step call donates, once per run; ``reason`` (nojit |
+    # runner) rides along when it donates nothing
+    "loop_donation": frozenset({"donated_leaves", "donated_bytes",
+                                "kept_leaves"}),
     "metrics": frozenset({"step", "window_steps", "values", "kinds"}),
     "sched": frozenset({"detail"}),
     # async heavy pipeline

@@ -355,6 +355,39 @@ def make_baseline_step(loss_fn: Callable, opt: optbase.Optimizer):
     return step
 
 
+def _donating_jit(step_fn):
+    """``jax.jit(step_fn, static_argnames=("work",))``, called the same
+    way, with the state's ``opt`` and ``rng`` donated and its ``params``
+    kept: XLA writes the new optimizer state into the old one's buffers
+    instead of the runtime allocating a buffer per leaf each step."""
+
+    def step(params, owned, batch, work, *rest):
+        opt_state, rng = owned
+        return step_fn(TrainState(params=params, opt=opt_state, rng=rng),
+                       batch, work, *rest)
+
+    jitted = jax.jit(step, static_argnames=("work",), donate_argnums=(1,))
+
+    def call(state: TrainState, batch, work, *rest):
+        return jitted(state.params, (state.opt, state.rng), batch, work,
+                      *rest)
+
+    return call
+
+
+def _donation_event(state: TrainState, reason: Optional[str]) -> dict:
+    """The ``loop_donation`` event's fields: what each step call donates
+    (``opt`` and ``rng``, or nothing and why) and keeps."""
+    owned = jax.tree_util.tree_leaves((state.opt, state.rng))
+    n_all = len(jax.tree_util.tree_leaves(state))
+    if reason is not None:
+        return dict(donated_leaves=0, donated_bytes=0, kept_leaves=n_all,
+                    reason=reason)
+    return dict(donated_leaves=len(owned),
+                donated_bytes=int(sum(x.nbytes for x in owned)),
+                kept_leaves=n_all - len(owned))
+
+
 def run_kfac_training(loss_fn, opt: kfac_lib.Kfac, params, batches,
                       n_tokens: int, seed: int = 0, jit: bool = True,
                       callback=None,
@@ -415,6 +448,13 @@ def run_kfac_training(loss_fn, opt: kfac_lib.Kfac, params, batches,
     The pre-spec flat kwargs (``mesh=``, ``writer=``, ``ckpt_dir=``, …)
     still work for one deprecation cycle — each warns once and folds
     into its spec (see :func:`repro.specs.consolidate_training_kwargs`).
+
+    ``callback(k, state, loss)`` runs after each step.  Its
+    ``state.params`` stay valid for as long as the caller keeps them;
+    its ``state.opt`` and ``state.rng`` only until the next step, which
+    (jitted, with no async runner) takes over their buffers.  A supplied
+    ``state`` is copied once, not consumed.  With a writer, one
+    ``loop_donation`` event says what each step donates.
     Returns (final TrainState, losses)."""
     dist, obs, ckpt, resilience = specs_lib.consolidate_training_kwargs(
         legacy, dist=dist, obs=obs, ckpt=ckpt, resilience=resilience,
@@ -427,14 +467,25 @@ def run_kfac_training(loss_fn, opt: kfac_lib.Kfac, params, batches,
     from repro.train import checkpoint as ckpt_lib
     from repro.train import health as health_lib
     sched = opt.scheduler()
+    runner = AsyncInverseRunner.for_opt(opt, writer=writer) \
+        if overlap else None
+    # The step donates the optimizer state and the key, which the loop
+    # owns; not the params, which callbacks may keep, nor the batches.
+    # The runner's worker reads ``state.opt`` after the step returns.
+    no_donation = ("nojit" if not jit else
+                   "runner" if runner is not None else None)
     k_off = 0
     if state is None:
         state = TrainState(params=params, opt=opt.init(params),
                            rng=jax.random.PRNGKey(seed))
     else:
         k_off = int(jax.device_get(state.opt.phase))
-    runner = AsyncInverseRunner.for_opt(opt, writer=writer) \
-        if overlap else None
+        if no_donation is None:
+            state = state._replace(
+                opt=jax.tree_util.tree_map(jnp.copy, state.opt),
+                rng=jnp.copy(state.rng))
+    if writer is not None:
+        writer.emit("loop_donation", **_donation_event(state, no_donation))
     meter = obs.make_meter(opt)
     if health or policy is not None:
         hcfg = health if isinstance(health, health_lib.HealthConfig) \
@@ -446,7 +497,9 @@ def run_kfac_training(loss_fn, opt: kfac_lib.Kfac, params, batches,
     else:
         step_fn = make_scheduled_kfac_step(loss_fn, opt, n_tokens,
                                            meter=meter)
-    if jit:
+    if no_donation is None:
+        step_fn = _donating_jit(step_fn)
+    elif jit:
         step_fn = jax.jit(step_fn, static_argnames=("work",))
     mbuf = meter.init() if meter is not None else None
     losses = []

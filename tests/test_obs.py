@@ -49,6 +49,8 @@ _SAMPLE_EVENTS = {
                     s_per_step=0.01),
     "log": dict(msg="hello"),
     "step": dict(step=0, loss=1.25, dt_s=0.01, phase="heavy"),
+    "loop_donation": dict(donated_leaves=12, donated_bytes=4096,
+                          kept_leaves=2),
     "metrics": dict(step=10, window_steps=10,
                     values={"work/stats_fired": 5.0},
                     kinds={"work/stats_fired": "counter"}),
