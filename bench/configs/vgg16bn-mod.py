@@ -54,3 +54,15 @@ def model_flops(sizes: dict, taps: dict, data: dict) -> float:
     rows = tap_rows(sizes, data)
     fwd = sum(2.0 * rows[n] * t.d_in * t.d_out for n, t in taps.items())
     return 3.0 * fwd
+
+
+def tiny(cell):
+    """The cell at widths a CPU test can hold, changed in place: two
+    stages of 8 and 16 channels on 8x8 images, FC 256 -> 20 -> 10, r=8,
+    n_stat=16, batch 4; every factor mode of the full cell still occurs
+    (Brand, low-rank from M, dense), and each step kind of the cell runs
+    among the checked steps."""
+    cell.sizes.update(stages=[8, 16], fc_hidden=20, img=8)
+    cell.job["data"].update(batch=4, pool=8)
+    cell.job["optimizer"].update(r=8, n_stat=16, max_dense_dim=64)
+    return cell

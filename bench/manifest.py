@@ -1,10 +1,13 @@
 """Finds every file of a cell by the names in ``BENCHMARK.json``.
 
 A configuration ``<c>`` is ``bench/configs/<c>.json`` (its sizes, as
-run) with ``bench/configs/<c>.py`` (what builds the program from them)
-and ``bench/reference/<c>.py`` (its plain float32 reference).  A traffic
-mix ``<t>`` is ``bench/traffic/<t>.json``.  A per-layer metric ``<m>``
-is ``bench/metrics/<m>.py``.  A cell ``<w>`` keeps the limits of its
+run) with ``bench/configs/<c>.py`` (what builds the program from them,
+and its tiny size for the CPU tests) and ``bench/reference/<c>.py`` (its
+plain float32 reference).  A traffic mix ``<t>`` is
+``bench/traffic/<t>.json``; the kind of batch its ``data`` block names,
+``<k>``, is ``bench/batches/<k>.py`` (the pool's generator and the
+faults the tests plant in a batch).  A per-layer metric ``<m>`` is
+``bench/metrics/<m>.py``.  A cell ``<w>`` keeps the limits of its
 correctness check in ``bench/limits/<w>.json``.  Adding any of these
 takes new files and entries only.
 """
@@ -51,6 +54,13 @@ def config_module(config: str):
 def reference_module(config: str):
     return load_module(BENCH / "reference" / f"{config}.py",
                        f"bench_reference_{_safe(config)}")
+
+
+def batches_module(kind: str):
+    path = BENCH / "batches" / f"{kind}.py"
+    if not path.is_file():
+        raise ValueError(f"no batch kind {kind!r} (bench/batches/)")
+    return load_module(path, f"bench_batches_{_safe(kind)}")
 
 
 def reference_train():

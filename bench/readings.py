@@ -11,8 +11,9 @@ the control, the reference computed in the precision below the one the
 configuration states (its file's ``control``), put in the program's
 place: the upper readings.  For every ``--fault-seeds`` seed, the
 reference with a fault planted where it runs: half of each batch left
-out and the mean taken over the rest (``half``), one label of each
-batch altered where the batch is made (``label``), and a step that
+out and the mean taken over the rest (``half``), one target of each
+batch altered where the batch is made (``label``; both as the cell's
+batch kind, ``bench/batches/<kind>.py``, plants them), and a step that
 leaves the parameters unchanged (``stale``; it reads 1 on ``change``
 and ``last`` by their definition, and is run for the other numbers).
 For every ``--nudge-seeds`` seed, a look at how far
@@ -37,17 +38,6 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(ROOT / "src"))
 
 NUDGE = 1e-7
-
-
-def _half(pool):
-    """Half the images and labels of each batch."""
-    return tuple(tuple(x[: x.shape[0] // 2] for x in b) for b in pool)
-
-
-def _alter(pool, data: dict):
-    """One label of each batch changed to the next class."""
-    n = int(data["classes"])
-    return tuple((x, y.at[0].set((y[0] + 1) % n)) for x, y in pool)
 
 
 class Nudged:
@@ -112,6 +102,7 @@ class Seed:
         self.loop_seed = seed % (2 ** 31 - 1)
         self.steps = int(cell.job["check_steps"])
         self.data = dict(cell.job["data"], **adapter.data_shape(cell.sizes))
+        self.batches = manifest.batches_module(self.data["kind"])
         self.n_tokens = adapter.n_tokens(self.data)
         self.pool = data_lib.make_pool(self.data, self.data_key)[:self.steps]
         t0 = time.perf_counter()
@@ -150,9 +141,10 @@ def readings(cell, seed: int, kind: str, shared: Seed = None) -> dict:
         got = s.reference(s.model, s.pool,
                           dtype=jnp.dtype(cell.sizes["control"]["params"]))
     elif kind == "half":
-        got = s.reference(s.model, _half(s.pool))
+        got = s.reference(s.model, tuple(map(s.batches.half, s.pool)))
     elif kind == "label":
-        got = s.reference(s.model, _alter(s.pool, s.data))
+        got = s.reference(s.model, tuple(s.batches.alter(b, s.data)
+                                         for b in s.pool))
     elif kind == "stale":
         apply = s.train._apply
         s.train._apply = lambda params, updates, scale: params

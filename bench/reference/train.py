@@ -4,7 +4,10 @@ It imports nothing of the program.
 
 B-KFAC (arXiv:2210.08494; K-FAC of arXiv:1503.05671), per tapped layer
 y = x W with W of shape (d_in, d_out) and per factor side (A over d_in,
-G over d_out):
+G over d_out).  A tap may be stacked: one leaf (*stack, d_in, d_out)
+holds a layer per index of ``stack`` (scanned layers), with a factor of
+each side per layer, held stacked as the program holds it; each layer's
+factor steps and preconditions on its own, as below.
 
 * statistics: X_A = xᵀ/√n, X_G = (∂L/∂y)ᵀ·n_tokens/√n over the layer's
   n = n_stat stats rows; the EA factor is M ← X Xᵀ on the first stats
@@ -22,8 +25,9 @@ G over d_out):
   with a Householder QR each, the eigen-decomposition of the projected
   core.  The sketch is drawn as the program draws it from the seed: the
   step's key split over the factor groups (factors of one side, mode and
-  rank, ordered by side, mode and rank), then over each group's factors
-  (by layer name, A before G);
+  rank, ordered by side, mode and rank), then over each group's slots
+  (by layer name, A before G; a stacked tap takes one slot per layer,
+  in the order of its flattened stack);
 * preconditioning: λ = φ·max D, then the spectrum continuation of §3.5
   (shift D down by its smallest positive mode, add that to λ), and
   S = (U_G D_G U_Gᵀ + λ_G I)⁻¹ Wgradᵀ (U_A D_A U_Aᵀ + λ_A I)⁻¹;
@@ -156,19 +160,46 @@ def _rsvd(M, r, r_o, n_iter, key):
 
 
 def slot_keys(taps, opt: dict, key) -> Dict[str, jax.Array]:
-    """The key of each factor's sketch at a step with key ``key``."""
+    """The keys of each factor's sketches at a step with key ``key``, of
+    shape (*stack, *key): one per layer of the factor's stack."""
     groups: Dict[tuple, list] = {}
-    for name, d_in, d_out in sorted(taps):
+    for name, d_in, d_out, stack in sorted(taps):
         for side, d in (("A", d_in), ("G", d_out)):
             mode, _ = mode_of(opt, d)
             groups.setdefault((d, mode, min(opt["r"], d)), []).append(
-                f"{name}/{side}")
+                (f"{name}/{side}", stack))
     order = sorted(groups, key=lambda g: (g[0], opt["n_stat"], g[1], g[2]))
     out = {}
     for gkey, spec in zip(jax.random.split(key, len(order)), order):
         members = groups[spec]
-        out.update(zip(members, jax.random.split(gkey, len(members))))
+        keys = jax.random.split(gkey, sum(math.prod(st)
+                                          for _, st in members))
+        at = 0
+        for fk, st in members:
+            n = math.prod(st)
+            out[fk] = keys[at:at + n].reshape(st + keys.shape[1:])
+            at += n
     return out
+
+
+def _per_layer(fn, stack, *stacked):
+    """``fn`` on each layer of the stacked trees, its outputs stacked back
+    into one leaf each (``fn`` itself where the stack is empty)."""
+    if not stack:
+        return fn(*stacked)
+    outs = [fn(*jax.tree_util.tree_map(lambda x, i=i: x[i], stacked))
+            for i in np.ndindex(*stack)]
+    return jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs).reshape(stack + xs[0].shape),
+        *outs)
+
+
+def _layer0(tree, stack):
+    """The first layer's slice of a stacked tree (the tree where the
+    stack is empty)."""
+    if not stack:
+        return tree
+    return jax.tree_util.tree_map(lambda x: x[(0,) * len(stack)], tree)
 
 
 def _factor_step(f, X, key, mode, width, opt, first, stats, light, heavy):
@@ -212,14 +243,14 @@ def init_state(model, sizes, job, key, dtype):
     taps = model.layers(sizes)
     adam = set(leaves) - {model.weight_path(t[0]) for t in taps}
     state = {"params": params, "factors": {}}
-    for name, d_in, d_out in taps:
+    for name, d_in, d_out, stack in taps:
         for side, d in (("A", d_in), ("G", d_out)):
             mode, width = mode_of(opt, d)
             state["factors"][f"{name}/{side}"] = {
-                "U": jnp.zeros((d, width), dtype),
-                "D": jnp.zeros((width,), dtype),
-                "M": jnp.zeros((d, d) if mode in _NEEDS_M else (1, 1),
-                               dtype)}
+                "U": jnp.zeros((*stack, d, width), dtype),
+                "D": jnp.zeros((*stack, width), dtype),
+                "M": jnp.zeros((*stack, *((d, d) if mode in _NEEDS_M
+                                          else (1, 1))), dtype)}
     state["m"] = {p: jnp.zeros_like(leaves[p]) for p in sorted(adam)}
     state["v"] = {p: jnp.zeros_like(leaves[p]) for p in sorted(adam)}
     return state
@@ -243,21 +274,23 @@ def _adamw(g, m, v, p, t, hp_key, lr):
 def _grads(params, batch, model, sizes_key, n_stat, n_tokens):
     """(loss, parameter gradients, stats panels X per factor, their
     norms): X_A = xᵀ/√n over the stats rows, X_G = (∂L/∂y)ᵀ·n_tokens/√n
-    over the same output rows."""
+    over the same output rows, each (*stack, d, n_stat); a stacked
+    factor's norm is over all its layers."""
     sizes = json.loads(sizes_key)
     dtype = jax.tree_util.tree_leaves(params)[0].dtype
     taps = model.layers(sizes)
-    probes = {name: jnp.zeros((n_stat, d_out), dtype)
-              for name, _, d_out in taps}
+    probes = {name: jnp.zeros((*stack, n_stat, d_out), dtype)
+              for name, _, d_out, stack in taps}
     grad_fn = jax.value_and_grad(model.loss_and_stats, argnums=(0, 1),
                                  has_aux=True)
     (loss, acts), (g, gprobe) = grad_fn(params, probes, batch, sizes,
                                         n_stat)
     rs = 1.0 / math.sqrt(n_stat)
     X = {}
-    for name, _, _ in taps:
-        X[f"{name}/A"] = acts[name].astype(dtype).T * rs
-        X[f"{name}/G"] = gprobe[name].T * (n_tokens * rs).astype(dtype)
+    for name, _, _, _ in taps:
+        X[f"{name}/A"] = jnp.swapaxes(acts[name].astype(dtype), -1, -2) * rs
+        X[f"{name}/G"] = (jnp.swapaxes(gprobe[name], -1, -2)
+                          * (n_tokens * rs).astype(dtype))
     norms = {k: _norm(x) for k, x in X.items()}
     return loss, g, X, norms
 
@@ -272,7 +305,7 @@ def _factor(f, X, key, mode, width, opt_key, flags):
 
 @jax.jit
 def _precond(gW, W, fa, fg, phi, lr, wd):
-    """−lr·(Sᵀ + wd·W) for one layer."""
+    """−lr·(Sᵀ + wd·W) for one layer, from the (U, D) of its factors."""
     S = _inv_right(gW.T, fa["U"], fa["D"], phi)
     S = _inv_right(S.T, fg["U"], fg["D"], phi)            # (d_in, d_out)
     return -lr * (S + wd * W)
@@ -288,6 +321,12 @@ def _apply(params, updates, scale):
     return jax.tree_util.tree_map(
         lambda p, u: (p + (u * scale).astype(u.dtype)).astype(p.dtype),
         params, updates)
+
+
+def _lowrank(f):
+    """The (U, D) of a factor: what preconditioning reads, without M (a
+    stacked M is then not sliced layer by layer for it)."""
+    return {"U": f["U"], "D": f["D"]}
 
 
 def _step(state, batch, k, flags, model, sizes, opt, n_tokens, key):
@@ -315,15 +354,19 @@ def _step(state, batch, k, flags, model, sizes, opt, n_tokens, key):
     factors = dict(state["factors"])
     taps = model.layers(sizes)
     keys = slot_keys(taps, opt, key)
-    for name, d_in, d_out in taps:
+    for name, d_in, d_out, stack in taps:
         for side, d in (("A", d_in), ("G", d_out)):
             mode, width = mode_of(opt, d)
             fk = f"{name}/{side}"
-            factors[fk] = _factor(factors[fk], X[fk], keys[fk], mode, width,
-                                  opt_key, flags)
+            factors[fk] = _per_layer(
+                functools.partial(_factor, mode=mode, width=width,
+                                  opt_key=opt_key, flags=flags),
+                stack, factors[fk], X[fk], keys[fk])
         path = model.weight_path(name)
-        updates[path] = _precond(G[path], P[path], factors[f"{name}/A"],
-                                 factors[f"{name}/G"], phi, lr, wd)
+        updates[path] = _per_layer(
+            functools.partial(_precond, phi=phi, lr=lr, wd=wd), stack,
+            G[path], P[path], _lowrank(factors[f"{name}/A"]),
+            _lowrank(factors[f"{name}/G"]))
     norm = jnp.sqrt(sum(_norm(u) ** 2 for u in updates.values()))
     scale = jnp.minimum(1.0, opt["clip"] / (norm + 1e-12))
     out["factors"] = factors
@@ -339,15 +382,17 @@ def _compile_side_by_side(model, sizes, opt, state, batch, n_tokens,
                           flag_sets, prec):
     """Call every distinct program of the steps once, on zeros, from a
     thread each, so their compilations run side by side (one by one, the
-    eigen-decompositions of a wide model take many minutes to compile)."""
+    eigen-decompositions of a wide model take many minutes to compile).
+    A stacked tap's programs are those of one layer."""
     dtype = jax.tree_util.tree_leaves(state["params"])[0].dtype
     jobs = [lambda: _grads(state["params"], batch, model,
                            json.dumps(sizes, sort_keys=True), opt["n_stat"],
                            jnp.float32(n_tokens))]
     opt_key = json.dumps(opt, sort_keys=True)
     seen = set()
-    for name, d_in, d_out in model.layers(sizes):
-        f = {side: state["factors"][f"{name}/{side}"] for side in "AG"}
+    for name, d_in, d_out, stack in model.layers(sizes):
+        f = {side: _layer0(state["factors"][f"{name}/{side}"], stack)
+             for side in "AG"}
         for side, d in (("A", d_in), ("G", d_out)):
             mode, width = mode_of(opt, d)
             X = jnp.zeros((d, opt["n_stat"]), dtype)
@@ -355,14 +400,16 @@ def _compile_side_by_side(model, sizes, opt, state, batch, n_tokens,
                 if (d, mode, width, flags) not in seen:
                     seen.add((d, mode, width, flags))
                     jobs.append(functools.partial(
-                        _factor, f[side], X, jax.random.PRNGKey(0), mode,
-                        width, opt_key, flags))
+                        _factor, f[side], X, jax.random.PRNGKey(0),
+                        mode=mode, width=width, opt_key=opt_key,
+                        flags=flags))
         if (d_in, d_out) not in seen:
             seen.add((d_in, d_out))
             z = jnp.zeros((), dtype)
             jobs.append(functools.partial(
                 _precond, jnp.zeros((d_in, d_out), dtype),
-                jnp.zeros((d_in, d_out), dtype), f["A"], f["G"], z, z, z))
+                jnp.zeros((d_in, d_out), dtype), _lowrank(f["A"]),
+                _lowrank(f["G"]), phi=z, lr=z, wd=z))
 
     def one(job):
         with jax.default_matmul_precision(prec):
@@ -421,7 +468,8 @@ def run(model, sizes: dict, job: dict, key, loop_seed: int, pool,
                 rec.setdefault("clip_scale", []).append(
                     float(state["clip_scale"]))
                 if k == 0:
-                    rec["D0"] = {fk: np.asarray(f["D"], np.float64).tolist()
+                    rec["D0"] = {fk: np.asarray(f["D"], np.float64)
+                                 .ravel().tolist()
                                  for fk, f in state["factors"].items()}
         rec["change"] = leaf_norm_diff(state["params"], p0)
         rec["last"] = leaf_norm_diff(state["params"], prev)

@@ -23,18 +23,18 @@ import jax
 import jax.numpy as jnp
 
 
-def layers(sizes: dict) -> List[Tuple[str, int, int]]:
-    """(name, d_in, d_out) of every tapped matmul, in forward order (none
-    is stacked over layers)."""
+def layers(sizes: dict) -> List[Tuple[str, int, int, Tuple[int, ...]]]:
+    """(name, d_in, d_out, stack) of every tapped matmul, in forward
+    order; none is stacked over layers, so every stack is ()."""
     out, c_in = [], sizes["channels"]
     for s, c in enumerate(sizes["stages"]):
         for j in range(sizes["convs_per_stage"]):
-            out.append((f"conv{s}_{j}", 9 * c_in, c))
+            out.append((f"conv{s}_{j}", 9 * c_in, c, ()))
             c_in = c
     h = sizes["img"] // sizes["pool"][0] ** len(sizes["stages"])
     w = sizes["img"] // sizes["pool"][1] ** len(sizes["stages"])
-    out.append(("fc0", h * w * sizes["stages"][-1], sizes["fc_hidden"]))
-    out.append(("fc1", sizes["fc_hidden"], sizes["n_classes"]))
+    out.append(("fc0", h * w * sizes["stages"][-1], sizes["fc_hidden"], ()))
+    out.append(("fc1", sizes["fc_hidden"], sizes["n_classes"], ()))
     return out
 
 
@@ -47,12 +47,12 @@ def init(sizes: dict, key, dtype=jnp.float32) -> Dict[str, dict]:
     convs = [t for t in taps if t[0].startswith("conv")]
     keys = jax.random.split(key, len(convs) + 2)
     params = {}
-    for k, (name, d_in, d_out) in zip(keys[:len(convs)], convs):
+    for k, (name, d_in, d_out, _) in zip(keys[:len(convs)], convs):
         params[name] = {
             "w": jax.random.normal(k, (d_in, d_out)) / jnp.sqrt(d_in),
             "b": jnp.zeros((d_out,)), "bn_s": jnp.ones((d_out,)),
             "bn_b": jnp.zeros((d_out,))}
-    for k, (name, d_in, d_out) in zip(keys[-2:], taps[-2:]):
+    for k, (name, d_in, d_out, _) in zip(keys[-2:], taps[-2:]):
         params[name] = {"w": jax.random.normal(k, (d_in, d_out))
                         / jnp.sqrt(d_in), "b": jnp.zeros((d_out,))}
     return jax.tree_util.tree_map(lambda x: x.astype(dtype), params)

@@ -34,6 +34,30 @@ def test_every_cell_loads(w):
     assert all(v > 0 for v in cell.limits.values())
 
 
+@pytest.mark.parametrize("w", MAN["workloads"], ids=lambda w: w["name"])
+def test_every_cell_keeps_the_module_contract(w):
+    """What the harness calls of a cell's configuration, reference and
+    batch kind is there, and the reference's taps are 4-tuples."""
+    cell = manifest.load_cell(w["name"])
+    config = manifest.config_module(cell.config)
+    for fn in ("build_model", "data_shape", "n_tokens", "model_flops",
+               "tiny"):
+        assert callable(getattr(config, fn, None)), fn
+    ref = manifest.reference_module(cell.config)
+    for fn in ("layers", "init", "loss_and_stats", "weight_path"):
+        assert callable(getattr(ref, fn, None)), fn
+    taps = ref.layers(cell.sizes)
+    assert taps
+    for name, d_in, d_out, stack in taps:
+        assert isinstance(name, str) and isinstance(ref.weight_path(name),
+                                                    str)
+        assert d_in > 0 and d_out > 0
+        assert isinstance(stack, tuple) and all(n > 0 for n in stack)
+    batches = manifest.batches_module(cell.job["data"]["kind"])
+    for fn in ("make_pool", "half", "alter"):
+        assert callable(getattr(batches, fn, None)), fn
+
+
 @pytest.mark.parametrize("m", MAN["per_layer"], ids=lambda m: m["name"])
 def test_every_metric_has_a_reader(m):
     assert callable(manifest.metric_module(m["name"]).value)

@@ -1,6 +1,8 @@
 """bench/run.py refuses to run without a TPU, and without the program;
 on the CPU at tiny sizes (the chip check skipped) a sound run comes out
-correct, and runs with the timed path broken underneath do not."""
+correct, and runs with the timed path broken underneath do not, in every
+cell and in the toy stacked cell (``toy/``: a scanned, layer-stacked
+tap beside an unstacked one, Brand and RSVD factors)."""
 import json
 import os
 import shutil
@@ -13,7 +15,7 @@ import manifest
 import run as bench_run
 import tiny
 
-CELLS = tiny.NAMES
+CELLS = tiny.NAMES + [tiny.TOY]
 
 
 def _cli(cwd, *extra):
@@ -57,8 +59,8 @@ def _run(cell, seed=5):
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_sound_run_is_correct(name):
-    cell = tiny.tiny_cell(name)
+def test_sound_run_is_correct(name, monkeypatch):
+    cell = tiny.cell(name, monkeypatch)
     out = _run(cell)
     assert out["correct"], out["checks"]
     assert list(out)[-1] == "checks"
@@ -89,29 +91,29 @@ def _wrap_loss(config, transform):
     return build
 
 
-def _half(batch):
-    x, y = batch
-    return x[: x.shape[0] // 2], y[: y.shape[0] // 2]
-
-
-def _label(batch):
-    """One image's label changed to the next class where the batch
-    reaches the loss."""
-    x, y = batch
-    return x, y.at[0].set((y[0] + 1) % 10)
+def _break_timed_path(cell, fault: str, monkeypatch) -> None:
+    """Plants ``fault`` under the timed path of ``cell``: a step that
+    returns the parameters unchanged (``stale``), or, as the cell's batch
+    kind plants them where the batch reaches the loss, half of the batch
+    left out (``half``) or one target altered (``label``)."""
+    from repro.train import loop
+    if fault == "stale":
+        monkeypatch.setattr(loop, "make_scheduled_kfac_step",
+                            _stale_kfac(loop.make_scheduled_kfac_step))
+        return
+    adapter = manifest.config_module(cell.config)
+    data = dict(cell.job["data"], **adapter.data_shape(cell.sizes))
+    batches = manifest.batches_module(data["kind"])
+    transform = {"half": batches.half,
+                 "label": lambda b: batches.alter(b, data)}[fault]
+    monkeypatch.setattr(adapter, "build_model",
+                        _wrap_loss(cell.config, transform))
 
 
 @pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("fault", ["stale", "half", "label"])
 def test_broken_timed_path_is_not_correct(name, fault, monkeypatch):
-    from repro.train import loop
-    cell = tiny.tiny_cell(name)
-    adapter = manifest.config_module(cell.config)
-    if fault == "stale":
-        monkeypatch.setattr(loop, "make_scheduled_kfac_step",
-                            _stale_kfac(loop.make_scheduled_kfac_step))
-    else:
-        monkeypatch.setattr(adapter, "build_model", _wrap_loss(
-            cell.config, _half if fault == "half" else _label))
+    cell = tiny.cell(name, monkeypatch)
+    _break_timed_path(cell, fault, monkeypatch)
     out = _run(cell)
     assert not out["correct"], out["checks"]

@@ -1,5 +1,5 @@
 """Tiny versions of the benchmark's cells, small enough for the CPU."""
-import copy
+import json
 import pathlib
 import sys
 
@@ -16,13 +16,37 @@ NAMES = [w["name"] for w in manifest.load_manifest()["workloads"]]
 
 
 def tiny_cell(name: str):
-    """The cell ``name`` at widths a CPU test can hold: two stages of 8
-    and 16 channels on 8x8 images, FC 256 -> 20 -> 10, r=8, n_stat=16,
-    batch 4; every factor mode of the full cell still occurs (Brand,
-    low-rank from M, dense), and each step kind of the cell runs among
-    the checked steps."""
-    cell = copy.deepcopy(manifest.load_cell(name))
-    cell.sizes.update(stages=[8, 16], fc_hidden=20, img=8)
-    cell.job["data"].update(batch=4, pool=8)
-    cell.job["optimizer"].update(r=8, n_stat=16, max_dense_dim=64)
-    return cell
+    """The cell ``name`` at its configuration's tiny size (``tiny`` in
+    ``bench/configs/<c>.py``)."""
+    cell = manifest.load_cell(name)
+    return manifest.config_module(cell.config).tiny(cell)
+
+
+TOY = "toy.stacked"
+TOY_DIR = pathlib.Path(__file__).resolve().parent / "toy"
+
+
+def toy_cell(monkeypatch):
+    """The toy stacked cell of ``toy/``, which no ``BENCHMARK.json``
+    names: its configuration, reference and batch kind (all named
+    ``toy``) are handed to the harness through the manifest's lookups,
+    for the length of the test."""
+    spec = json.loads((TOY_DIR / "cell.json").read_text())
+    for lookup, stem in (("config_module", "config"),
+                         ("reference_module", "reference"),
+                         ("batches_module", "batches")):
+        mod = manifest.load_module(TOY_DIR / f"{stem}.py", f"bench_toy_{stem}")
+        real = getattr(manifest, lookup)
+        monkeypatch.setattr(manifest, lookup,
+                            lambda name, real=real, mod=mod:
+                            mod if name == "toy" else real(name))
+    e2e = [m for m in manifest.load_manifest()["end_to_end"]
+           if "workloads" not in m]
+    return manifest.Cell(name=TOY, config="toy", traffic="toy", chips=1,
+                         sizes=spec["sizes"], job=spec["job"],
+                         limits=spec["limits"], end_to_end=e2e, per_layer=[])
+
+
+def cell(name: str, monkeypatch):
+    """``tiny_cell(name)``, or the toy stacked cell."""
+    return toy_cell(monkeypatch) if name == TOY else tiny_cell(name)
